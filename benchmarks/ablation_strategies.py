@@ -13,6 +13,7 @@ import os
 import numpy as np
 
 from repro.core.spec_engine import SpecConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import TASKS, ensure_dirs, get_tables, get_trained, measure
 
@@ -50,6 +51,7 @@ def run(out_dir: str = "experiments/results", max_new: int = 48) -> dict:
 
 
 def main():
+    enable_compile_cache()
     res = run()
     print("fig4_ablations ->", res["csv"])
     for task, s in res["summary"].items():

@@ -61,6 +61,7 @@ import numpy as np
 from repro.core.spec_engine import SpecConfig
 from repro.data.datasets import make_prompts
 from repro.launch import hostdev
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import ServingEngine
 
 from .common import get_tables, get_trained, ensure_dirs
@@ -699,6 +700,7 @@ def run(n: int = 24, rate_hz: float = 4.0, max_batch: int = 4,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=24)
     ap.add_argument("--rate", type=float, default=4.0)

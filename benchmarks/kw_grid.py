@@ -15,6 +15,7 @@ import os
 from repro.configs import get_config
 from repro.core.phase import slowdown
 from repro.core.spec_engine import SpecConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import TASKS, ensure_dirs, get_tables, get_trained, measure
 
@@ -57,6 +58,7 @@ def run(out_dir: str = "experiments/results", full: bool = False,
 
 
 def main():
+    enable_compile_cache()
     res = run()
     print("fig3_kw_grid ->", res["csv"])
     for task, (sp, kw, tpc) in res["best"].items():

@@ -8,12 +8,15 @@ Prints ``name,us_per_call,derived`` CSV rows:
 """
 from __future__ import annotations
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def _row(name, us_per_call, derived):
     print(f"{name},{us_per_call},{derived}", flush=True)
 
 
 def main() -> None:
+    enable_compile_cache()
     print("name,us_per_call,derived")
 
     from . import phase_transition

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro.core.drafters import mixed_draft
 from repro.core.spec_engine import SpecConfig, generate
 from repro.kernels import dispatch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 
 from .common import ensure_dirs, get_tables, get_trained, task_prompts
@@ -119,6 +120,7 @@ def run_backends(max_len: int = 192, gen_tokens: int = 24,
 
 
 def main():
+    enable_compile_cache()
     for name, us, derived in run()["rows"]:
         print(f"{name:24s} {us:10.0f} us   {derived}")
     res = run_backends()

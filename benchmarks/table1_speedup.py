@@ -14,6 +14,7 @@ import os
 from repro.configs import get_config
 from repro.core.phase import slowdown
 from repro.core.spec_engine import SpecConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import (SIZES, TASKS, ensure_dirs, get_tables, get_trained,
                      measure)
@@ -59,6 +60,7 @@ def run(out_dir: str = "experiments/results", max_new: int = 48) -> dict:
 
 
 def main():
+    enable_compile_cache()
     res = run()
     print("table1_speedup ->", res["csv"])
     for size, task, label, kw, tpc, sp, cpu_sp in res["rows"]:

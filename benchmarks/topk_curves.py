@@ -10,6 +10,7 @@ import csv
 import os
 
 from repro.core.spec_engine import SpecConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import ensure_dirs, get_tables, get_trained, measure
 
@@ -40,6 +41,7 @@ def run(out_dir: str = "experiments/results", max_new: int = 48) -> dict:
 
 
 def main():
+    enable_compile_cache()
     res = run()
     print("fig2_topk_curves ->", res["csv"])
     for (task, strat, w, k), v in sorted(res["results"].items()):

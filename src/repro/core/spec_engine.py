@@ -872,18 +872,22 @@ def greedy_reference(params, cfg: ModelConfig, prompt: jnp.ndarray,
     L = P + max_new_tokens
     buf = jnp.zeros((B, L), jnp.int32)
     buf = jax.lax.dynamic_update_slice(buf, prompt.astype(jnp.int32), (0, 0))
-
-    @jax.jit
-    def step(buf, cur):
-        logits, _ = M.forward(params, cfg, tokens=buf)
-        nxt = jnp.take_along_axis(
-            jnp.argmax(logits, axis=-1).astype(jnp.int32),
-            (cur - 1)[None].repeat(B, 0)[:, None], axis=1)[:, 0]
-        return buf.at[:, cur].set(nxt)
-
     for i in range(max_new_tokens):
-        buf = step(buf, jnp.asarray(P + i))
+        buf = _greedy_reference_step(params, cfg, buf, jnp.asarray(P + i))
     return buf
+
+
+# params are an ARGUMENT of the reference steps, never a closure: jit
+# inlines closed-over arrays into the program as constants, which at
+# published widths would put the whole model into the HLO
+@functools.partial(jax.jit, static_argnums=(1,))
+def _greedy_reference_step(params, cfg: ModelConfig, buf, cur):
+    B = buf.shape[0]
+    logits, _ = M.forward(params, cfg, tokens=buf)
+    nxt = jnp.take_along_axis(
+        jnp.argmax(logits, axis=-1).astype(jnp.int32),
+        (cur - 1)[None].repeat(B, 0)[:, None], axis=1)[:, 0]
+    return buf.at[:, cur].set(nxt)
 
 
 def sampling_reference(params, cfg: ModelConfig, prompt: jnp.ndarray,
@@ -907,15 +911,17 @@ def sampling_reference(params, cfg: ModelConfig, prompt: jnp.ndarray,
     temp = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32), (B,))
     topp = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), (B,))
     keys = per_row_keys(jnp.asarray(rng, jnp.uint32), B)
-
-    @jax.jit
-    def step(buf, keys, cur):
-        logits, _ = M.forward(params, cfg, tokens=buf)
-        row_logits = logits[:, cur - 1]                       # (B, V)
-        nk = jax.vmap(jax.random.split)(keys)
-        nxt = sample_token(row_logits, nk[:, 0], temp, topp)
-        return buf.at[:, cur].set(nxt), nk[:, 1]
-
     for i in range(max_new_tokens):
-        buf, keys = step(buf, keys, jnp.asarray(P + i))
+        buf, keys = _sampling_reference_step(params, cfg, buf, keys,
+                                             jnp.asarray(P + i), temp, topp)
     return buf
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _sampling_reference_step(params, cfg: ModelConfig, buf, keys, cur, temp,
+                             topp):
+    logits, _ = M.forward(params, cfg, tokens=buf)
+    row_logits = logits[:, cur - 1]                           # (B, V)
+    nk = jax.vmap(jax.random.split)(keys)
+    nxt = sample_token(row_logits, nk[:, 0], temp, topp)
+    return buf.at[:, cur].set(nxt), nk[:, 1]

@@ -25,6 +25,7 @@ buffer length at which that per-step repad never happens.
 """
 from __future__ import annotations
 
+import re
 from typing import Optional, Tuple
 
 import jax
@@ -50,6 +51,24 @@ def resolve_backend(backend: str) -> str:
 
 def use_pallas(backend: str) -> bool:
     return resolve_backend(backend) == "pallas"
+
+
+# every pallas_call names its kernel; the name survives into the op
+# metadata of the compiled program ("<...>/<name>/pallas_call")
+_KERNEL_OP = re.compile(r'op_name="[^"]*?(\w+)/pallas_call')
+
+
+def kernels_in_hlo(hlo_text: str) -> Tuple[str, ...]:
+    """Names of the Pallas kernels compiled for the TPU in an optimized HLO
+    text, sorted: only ``tpu_custom_call`` ops count, so a kernel that ran
+    in interpret mode (lowered to plain XLA ops) is not reported."""
+    found = set()
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            m = _KERNEL_OP.search(line)
+            if m:
+                found.add(m.group(1))
+    return tuple(sorted(found))
 
 
 def unique_sweep_widths(arms) -> Tuple[int, ...]:
@@ -160,8 +179,7 @@ def verify_attention_paged(q, k_pool, v_pool, page_table, k_tail, v_tail,
 # context N-gram match/hash sweep
 # ----------------------------------------------------------------------------
 def ngram_sweep(buf: jnp.ndarray, query: jnp.ndarray, cur_len: jnp.ndarray,
-                *, w: int, backend: str,
-                block_l: int = 0) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                *, w: int, backend: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Backend-dispatched match/hash sweep over every context position.
 
     buf: (B, L) int32; query: (B, q); cur_len: (B,).
@@ -179,10 +197,9 @@ def ngram_sweep(buf: jnp.ndarray, query: jnp.ndarray, cur_len: jnp.ndarray,
     so dispatching it over a data-sharded ``buf`` inside the sharded
     spec_step would fail to lower (or gather the buffer every step).
     """
-    bl = block_l if block_l else ops.DEFAULT_BLOCK_L
     from ..distributed import act_sharding
     if use_pallas(backend) and not act_sharding.installed():
-        return ops.ngram_match_op(buf, query, cur_len, w=w, block_l=bl,
+        return ops.ngram_match_op(buf, query, cur_len, w=w,
                                   interpret=default_interpret())
     B, L = buf.shape
     q = query.shape[1]

@@ -15,7 +15,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import ref
-from .ngram_match import DEFAULT_BLOCK_L, ngram_match_call
+from .ngram_match import LANE as NGRAM_LANE
+from .ngram_match import TILE as NGRAM_TILE
+from .ngram_match import ngram_match_call
 from .spec_attention import (DEFAULT_BLOCK_S, paged_spec_attention_call,
                              spec_attention_call)
 
@@ -118,26 +120,26 @@ def spec_attention_ref_op(q, k_cache, v_cache, k_tail, v_tail, cur_len, *,
     return out.reshape(B, H, K, W1, hd).transpose(0, 2, 3, 1, 4)
 
 
-@functools.partial(jax.jit, static_argnames=("w", "block_l", "interpret"))
+@functools.partial(jax.jit, static_argnames=("w", "interpret"))
 def ngram_match_op(buf, query, cur_len, *, w: int,
-                   block_l: int = DEFAULT_BLOCK_L,
                    interpret: bool | None = None):
     """buf: (B, L) int32; query: (B, q); cur_len: (B,).
 
-    Returns (match (B, L) int32, hash (B, L) uint32)."""
+    Returns (match (B, L) int32, hash (B, L) uint32).  The kernel walks L
+    in tiles of ``ngram_match.TILE`` positions."""
     if interpret is None:
         interpret = _default_interpret()
     B, L = buf.shape
-    q = query.shape[1]
-    bl = min(block_l, L)
-    Lp = -(-L // bl) * bl           # pad positions to whole blocks; padded
-    pad = jnp.full((B, Lp - L + q + w), -1, jnp.int32)   # slots can never
-    bufp = jnp.concatenate([buf.astype(jnp.int32), pad], axis=1)  # match
-    fn = lambda b, qq, c: ngram_match_call(b, qq, c[None], w=w, block_l=bl,
-                                           interpret=interpret)
-    m, h = jax.vmap(fn)(bufp, query.astype(jnp.int32),
-                        cur_len.astype(jnp.int32))
-    return m[:, :L], h[:, :L]
+    Lp = -(-L // NGRAM_TILE) * NGRAM_TILE
+    # whole tiles plus the last tile's halo; padded positions fail the
+    # kernel's cur_len guard, so they never match
+    pad = jnp.full((B, Lp + NGRAM_TILE - L), -1, jnp.int32)
+    bufp = jnp.concatenate([buf.astype(jnp.int32), pad], axis=1)
+    m, h = ngram_match_call(bufp.reshape(B, -1, NGRAM_LANE),
+                            query.astype(jnp.int32),
+                            cur_len.astype(jnp.int32), w=w,
+                            interpret=interpret)
+    return m.reshape(B, Lp)[:, :L], h.reshape(B, Lp)[:, :L]
 
 
 @functools.partial(jax.jit,

@@ -43,7 +43,9 @@ def spec_attention_ref(q, k_cache, v_cache, k_tail, v_tail, cur_len, *,
 
 def ngram_match_ref(buf_padded: jnp.ndarray, query: jnp.ndarray,
                     cur_len: jnp.ndarray, *, w: int):
-    """Oracle for ngram_match_call. buf_padded: (L+q+w,); returns ((L,), (L,))."""
+    """Oracle for the match/hash sweep of one sequence.
+
+    buf_padded: (L+q+w,) (-1 padded); returns (match (L,), hash (L,))."""
     q = query.shape[0]
     L = buf_padded.shape[0] - q - w
     pos = jnp.arange(L)

@@ -191,6 +191,7 @@ def spec_attention_call(q, k_cache, v_cache, k_tail, v_tail, cur_len, *,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, KW1, hd), q.dtype),
         interpret=interpret,
+        name="spec_attention",
     )(*operands)
 
 
@@ -258,4 +259,5 @@ def paged_spec_attention_call(q, k_pool, v_pool, page_table, k_tail, v_tail,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, KW1, hd), q.dtype),
         interpret=interpret,
+        name="paged_spec_attention",
     )(*operands)

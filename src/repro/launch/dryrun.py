@@ -20,6 +20,10 @@ if __name__ == "__main__":
     # IMPORTING this module (test collection, benchmarks borrowing
     # collective_bytes) must never mutate jax device state.  The append-
     # don't-clobber / respect-caller-count policy lives in hostdev.
+    # The dry-run lowers for placeholder CPU devices: pin the platform so
+    # that neither this process nor the per-case children it starts
+    # (_drive_subprocesses) ever claims a TPU on a host that has one.
+    os.environ["JAX_PLATFORMS"] = "cpu"
     from repro.launch.hostdev import ensure_host_devices
     ensure_host_devices(512)
 

@@ -20,12 +20,14 @@ import jax
 from repro.configs import ALL_ARCHS, get_smoke_config
 from repro.core.spec_engine import SpecConfig
 from repro.data.datasets import make_prompts
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import ServingEngine
 from repro.train import AdamWConfig, init_train_state, make_train_step
 from repro.train.checkpoint import load
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ALL_ARCHS, default="mistral-7b")
     ap.add_argument("--ckpt", default="", help="params npz (else quick-train)")

@@ -226,10 +226,12 @@ class ServingEngine:
     def build_tables(self, k_max: int = 16, w_max: int = 16,
                      batch: int = 256) -> NGramTables:
         """One-off model sweep (paper: <1 min for a 7B on one A100)."""
-        fwd = jax.jit(lambda t: M.forward(self.params, self.cfg,
-                                          tokens=t)[0][:, -1])
+        # params ride as an argument: a closure would be inlined into the
+        # program as a constant (the whole model, at published widths)
+        fwd = jax.jit(lambda p, t: M.forward(p, self.cfg, tokens=t)[0][:, -1])
         with self._act():
-            topk, chain = build_bigram(fwd, self.cfg.vocab_size, k_max=k_max,
+            topk, chain = build_bigram(lambda t: fwd(self.params, t),
+                                       self.cfg.vocab_size, k_max=k_max,
                                        w_max=w_max, batch=batch)
         uni = build_unigram(self.params["embed"]["embedding"],
                             self.params["embed"].get(

@@ -231,7 +231,8 @@ def test_host_sync_fires_on_debug_callback():
         jax.debug.print("x={x}", x=x)
         return x + 1
     got = jr.jaxpr_sync_findings(g, (jnp.ones(3),), "<p>")
-    assert len(got) == 1 and "debug_callback" in got[0].context
+    # jax.debug.print binds the debug_print primitive (JAX 0.9)
+    assert len(got) == 1 and "debug_print" in got[0].context
 
 
 def test_host_sync_walks_nested_jaxprs():

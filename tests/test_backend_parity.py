@@ -20,7 +20,7 @@ from repro.core.drafters import context_ngram_draft, match_hash_sweep
 from repro.core.ngram_tables import NGramTables, build_bigram, build_unigram
 from repro.core.spec_engine import (SpecConfig, generate, greedy_reference,
                                     init_decode_state, spec_step)
-from repro.kernels import dispatch, ops
+from repro.kernels import dispatch, ngram_match, ops
 from repro.models import model as M
 from repro.models.config import ModelConfig
 
@@ -94,21 +94,23 @@ def test_context_drafts_identical_backends(q, w):
 
 
 def test_sweep_identical_backends_nonmultiple_block():
-    """L that does not divide block_l exercises the ngram padding path."""
+    """L that is not a whole number of kernel tiles exercises the ngram
+    padding path."""
     rng = np.random.default_rng(7)
-    buf = jnp.asarray(rng.integers(0, 6, (2, 50)), jnp.int32)
-    cur = jnp.asarray([50, 33], jnp.int32)
+    buf = jnp.asarray(rng.integers(0, 6, (2, 1500)), jnp.int32)
+    cur = jnp.asarray([1500, 733], jnp.int32)
     q, w = 2, 3
     qx, mx, hx = match_hash_sweep(buf, cur, q, w, backend="xla")
     qp, mp, hp = match_hash_sweep(buf, cur, q, w, backend="pallas")
     np.testing.assert_array_equal(np.asarray(qx), np.asarray(qp))
     np.testing.assert_array_equal(np.asarray(mx), np.asarray(mp))
     np.testing.assert_array_equal(np.asarray(hx), np.asarray(hp))
-    # padding path explicitly: block_l=32 on L=50 pads to 64
-    m32, h32 = dispatch.ngram_sweep(buf, qx, cur, w=w, backend="pallas",
-                                    block_l=32)
-    np.testing.assert_array_equal(np.asarray(m32), np.asarray(mx))
-    np.testing.assert_array_equal(np.asarray(h32), np.asarray(hx))
+    # padding path explicitly: L=1500 pads to two 1024-position tiles, the
+    # second mostly padding
+    assert ngram_match.TILE == 1024
+    m2, h2 = dispatch.ngram_sweep(buf, qx, cur, w=w, backend="pallas")
+    np.testing.assert_array_equal(np.asarray(m2), np.asarray(mx))
+    np.testing.assert_array_equal(np.asarray(h2), np.asarray(hx))
 
 
 # ---------------------------------------------------------------------------

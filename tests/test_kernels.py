@@ -56,17 +56,20 @@ def test_spec_attention_empty_cache():
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("q,w,L,bl", [(1, 3, 64, 32), (2, 5, 128, 32),
-                                      (3, 8, 256, 64), (1, 1, 32, 32)])
-def test_ngram_match_sweep(q, w, L, bl):
+@pytest.mark.parametrize("q,w,L", [(1, 3, 64), (2, 5, 128), (3, 8, 256),
+                                   (1, 1, 32), (2, 5, 1100), (3, 8, 2500),
+                                   (1, 130, 1500), (2, 5, 2048)])
+def test_ngram_match_sweep(q, w, L):
+    """One tile and several, a padded tail tile, an L of whole tiles, and
+    windows that cross a lane row (s > 128) all match the oracle bit for
+    bit."""
     rng = np.random.default_rng(q * 100 + w)
     B = 2
     buf = jnp.asarray(rng.integers(0, 6, (B, L)), jnp.int32)
     qs = rng.integers(0, L - q)
     query = buf[:, qs:qs + q]
     cur = jnp.asarray(rng.integers(q, L + 1, B), jnp.int32)
-    m, h = ops.ngram_match_op(buf, query, cur, w=w, block_l=bl,
-                              interpret=True)
+    m, h = ops.ngram_match_op(buf, query, cur, w=w, interpret=True)
     bufp = jnp.concatenate([buf, jnp.full((B, q + w), -1, jnp.int32)], 1)
     m_r, h_r = jax.vmap(lambda b, qq, c: ref.ngram_match_ref(
         b, qq, c[None], w=w))(bufp, query, cur)
@@ -82,8 +85,7 @@ def test_ngram_match_agrees_with_drafter_hash():
     buf = jnp.asarray(rng.integers(0, 5, (1, L)), jnp.int32)
     query = buf[:, 10:12]
     cur = jnp.asarray([50], jnp.int32)
-    m, h = ops.ngram_match_op(buf, query, cur, w=w, block_l=32,
-                              interpret=True)
+    m, h = ops.ngram_match_op(buf, query, cur, w=w, interpret=True)
     grams = _gram_matrix(buf[0], q + w)
     h_drafter = _hash_rows(grams[:, q:])
     np.testing.assert_array_equal(np.asarray(h[0, :grams.shape[0]]),
