@@ -1,0 +1,191 @@
+"""The plain reference: the configuration's forward pass in float32.
+
+Straight ``jax.numpy`` at ``Precision.HIGHEST`` on every product, with no
+cache, no kernel and no batching trick: embedding, then per layer a norm,
+causal attention (grouped key/value heads, rotary embedding on the first
+``rotary`` dimensions of each head in the half-split layout, an optional
+sliding window), a norm and a SwiGLU MLP, each added to the residual; a
+final norm and the output head.  It imports nothing of the program and
+reads only the benchmark's own weights (``model.make_weights``).  It runs
+layer by layer, a few rows at a time, so that it fits beside the weights.
+
+``quant=True`` is the control: the same forward with both operands of
+every weight product rounded to float8 (e4m3, one scale per row of
+activations and per output column of weights) -- the precision one step
+below the bfloat16 the configurations state.
+"""
+from __future__ import annotations
+
+import functools
+import json
+from typing import Dict, List
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .model import Dims
+
+HI = jax.lax.Precision.HIGHEST
+F8 = jnp.float8_e4m3fn
+F8_MAX = 448.0
+
+
+def _fp8(x, axis):
+    """Round ``x`` to float8 e4m3 with an absmax scale along ``axis``."""
+    s = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / F8_MAX
+    s = jnp.where(s > 0, s, 1.0)
+    return (x / s).astype(F8).astype(jnp.float32) * s
+
+
+def _mm(x, w, quant: bool):
+    """x (..., k) @ w (k, n) in float32; float8 operands for the control."""
+    w = w.astype(jnp.float32)
+    if quant:
+        x, w = _fp8(x, -1), _fp8(w, 0)
+    return jnp.einsum("...k,kn->...n", x, w, precision=HI)
+
+
+def _norm(x, w, b, m: Dims):
+    if m.layernorm:
+        mu = x.mean(-1, keepdims=True)
+        var = ((x - mu) ** 2).mean(-1, keepdims=True)
+        y = (x - mu) / jnp.sqrt(var + m.eps)
+        return y * w.astype(jnp.float32) + b.astype(jnp.float32)
+    y = x / jnp.sqrt((x * x).mean(-1, keepdims=True) + m.eps)
+    return y * w.astype(jnp.float32)
+
+
+def _rope(x, m: Dims):
+    """x (B, T, N, hd): rotate the first ``m.rotary`` dims, half-split."""
+    r = m.rotary
+    if r == 0:
+        return x
+    T = x.shape[1]
+    inv = 1.0 / (m.theta ** (np.arange(0, r, 2, dtype=np.float64) / r))
+    ang = np.arange(T, dtype=np.float64)[:, None] * inv[None, :]
+    cos = jnp.asarray(np.cos(ang), jnp.float32)[None, :, None, :]
+    sin = jnp.asarray(np.sin(ang), jnp.float32)[None, :, None, :]
+    x1, x2, rest = x[..., :r // 2], x[..., r // 2:r], x[..., r:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest],
+                           -1)
+
+
+def _layer(x, w, i, m: Dims, quant: bool):
+    """One decoder layer ``i`` of the stacked weights ``w``."""
+    B, T, _ = x.shape
+    g = lambda n: w[n][i]
+    b = (lambda n: g(n)) if m.layernorm else (lambda n: None)
+    h = _norm(x, g("ln1_w"), b("ln1_b"), m)
+    q = _rope(_mm(h, g("wq"), quant).reshape(B, T, m.heads, m.hd), m)
+    k = _rope(_mm(h, g("wk"), quant).reshape(B, T, m.kv, m.hd), m)
+    v = _mm(h, g("wv"), quant).reshape(B, T, m.kv, m.hd)
+    q = q.reshape(B, T, m.kv, m.heads // m.kv, m.hd)
+    s = jnp.einsum("bqkgh,bskh->bkgqs", q, k, precision=HI) / m.hd ** 0.5
+    qi = np.arange(T)[:, None]
+    ki = np.arange(T)[None, :]
+    keep = ki <= qi
+    if m.window is not None:
+        keep = keep & (ki > qi - int(m.window))
+    s = jnp.where(jnp.asarray(keep), s, -jnp.inf)
+    a = jnp.einsum("bkgqs,bskh->bqkgh", jax.nn.softmax(s, -1), v,
+                   precision=HI).reshape(B, T, m.heads * m.hd)
+    x = x + _mm(a, g("wo"), quant)
+    h = _norm(x, g("ln2_w"), b("ln2_b"), m)
+    f = jax.nn.silu(_mm(h, g("w_gate"), quant)) * _mm(h, g("w_up"), quant)
+    return x + _mm(f, g("w_down"), quant)
+
+
+@functools.lru_cache(maxsize=None)
+def _fns(c_json: str, quant: bool):
+    m = Dims(json.loads(c_json))
+    layer = jax.jit(lambda x, w, i: _layer(x, w, i, m, quant))
+    embed = jax.jit(lambda e, t: e[t].astype(jnp.float32))
+
+    def head(x, w, P, n_max):
+        # positions P-1 .. P+n_max-2 of each row choose its served tokens
+        idx = P[:, None] - 1 + jnp.arange(n_max)[None, :]
+        idx = jnp.clip(idx, 0, x.shape[1] - 1)
+        h = jnp.take_along_axis(x, idx[..., None], 1)
+        h = _norm(h, w["norm_f_w"], w.get("norm_f_b"), m)
+        out = w["embed"].T if m.tie else w["lm_head"]
+        return _mm(h, out, quant)
+
+    return layer, embed, jax.jit(head, static_argnums=3)
+
+
+def logits(c: dict, w, rows: np.ndarray, P: np.ndarray, n_max: int,
+           quant: bool = False) -> jax.Array:
+    """(B, n_max, V) float32 logits at positions P-1 .. P+n_max-2 of each
+    row of ``rows`` (B, T) -- the positions that choose the tokens served
+    after a prompt of P tokens."""
+    m = Dims(c)
+    layer, embed, head = _fns(json.dumps(c, sort_keys=True), quant)
+    x = embed(w["embed"], jnp.asarray(rows))
+    for i in range(m.layers):
+        x = layer(x, w, jnp.int32(i))
+    return head(x, w, jnp.asarray(P, jnp.int32), n_max)
+
+
+@jax.jit
+def _gaps(ref, served, n, ctrl):
+    """Per position: how far the served token's reference logit lies below
+    the reference's best, and the same for the token the control puts
+    first.  Positions at or past ``n`` read 0."""
+    best = ref.max(-1)
+    got = jnp.take_along_axis(ref, served[..., None], -1)[..., 0]
+    pick = jnp.argmax(ctrl, -1)
+    alt = jnp.take_along_axis(ref, pick[..., None], -1)[..., 0]
+    live = jnp.arange(ref.shape[1])[None, :] < n[:, None]
+    return (jnp.where(live, best - got, 0.0),
+            jnp.where(live, best - alt, 0.0))
+
+
+def row_chunk(m: Dims, T: int, n_max: int, budget: float = 2e9) -> int:
+    """Rows per reference call that keep its largest temporaries (the
+    attention scores of one layer, three sets of logits) under budget."""
+    per_row = 4.0 * (m.heads * T * T + 3 * n_max * m.vocab + 8 * T * m.d)
+    return max(1, int(budget // per_row))
+
+
+def served_gaps(c: dict, w, rows: List[np.ndarray], served: List[np.ndarray],
+                T: int, n_max: int, control: bool = False) -> Dict:
+    """The reference's reading of served tokens.
+
+    ``rows[i]`` is the token row the program prefilled for request i (its
+    prompt as bucketed), ``served[i]`` the tokens it served after it.
+    Returns the widest gap over every served token (``served_gap``) and,
+    with ``control``, the widest gap of the tokens the float8 control puts
+    first at the same positions (``control_gap``)."""
+    m = Dims(c)
+    step = row_chunk(m, T, n_max)
+    # pad to whole chunks (rows with nothing served) so that every call
+    # has one shape and compiles once
+    B = -(-len(rows) // step) * step
+    toks = np.zeros((B, T), np.int32)
+    P = np.ones(B, np.int32)
+    S = np.zeros((B, n_max), np.int32)
+    n = np.zeros(B, np.int32)
+    for i, (r, s) in enumerate(zip(rows, served)):
+        P[i], n[i] = len(r), len(s)
+        toks[i, :len(r)] = r
+        toks[i, len(r):len(r) + len(s)] = s
+        S[i, :len(s)] = s
+    served_gap, control_gap = [], []
+    for lo in range(0, B, step):
+        sl = slice(lo, lo + step)
+        ref = logits(c, w, toks[sl], P[sl], n_max)
+        ctl = logits(c, w, toks[sl], P[sl], n_max, quant=True) \
+            if control else ref
+        g, a = _gaps(ref, jnp.asarray(S[sl]), jnp.asarray(n[sl]), ctl)
+        served_gap.append(np.asarray(g))
+        control_gap.append(np.asarray(a))
+        del ref, ctl
+    served_gap = np.concatenate(served_gap)
+    out = {"served_gap": float(served_gap.max()),
+           "served_tokens": int(n.sum()),
+           "served_argmax": int(((served_gap == 0) &
+                                 (np.arange(n_max)[None] < n[:, None])).sum())}
+    if control:
+        out["control_gap"] = float(np.concatenate(control_gap).max())
+    return out
