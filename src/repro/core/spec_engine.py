@@ -625,136 +625,160 @@ def _spec_body(params, cfg: ModelConfig, spec: SpecConfig,
         use_keys, carry_keys = nk[:, 0], nk[:, 1]
     else:
         use_keys, carry_keys = None, s.rng_key
-    last = jnp.take_along_axis(buf_c, (len_c - 1)[:, None], axis=1)[:, 0]
-    if adaptive:
-        # per-slot, per-step arm selection INSIDE the jit: UCB over the
-        # slot's own (B, A) stats, then mask the fixed (k_max, w_max)
-        # shapes down to the chosen arm — no recompile can ever occur
-        slow = (tree_arm_slowdowns(cfg, spec.arms, spec.tree_branch,
-                                   spec.adapt_ell) if spec.tree
-                else arm_slowdowns(cfg, spec.arms, spec.adapt_ell))
-        arm = choose_arms(st, slow, spec.adapt_explore)         # (B,)
-        k_eff = jnp.asarray([a[0] for a in spec.arms], jnp.int32)[arm]
-        w_eff = jnp.asarray([a[1] for a in spec.arms], jnp.int32)[arm]
-        drafts, valid, n_ctx = _draft_adaptive(spec, tables, buf_c, len_c,
-                                               last, arm)
-    else:
-        arm = k_eff = w_eff = None
-        drafts, valid, n_ctx = _draft(spec, tables, buf_c, len_c, last)
-    if spec.tree:
-        # ONE (B, 1, N+1) verify call scores the whole token tree; the
-        # topology's ancestor mask + per-level positions make every
-        # root-to-leaf path bit-identical to a linear row of its tokens
-        nodes = T.fill_tree(topo, drafts, tables,
-                            buf=buf_c, buf_len=len_c)           # (B, N)
-        rows = jnp.concatenate([last[:, None], nodes],
-                               axis=1)[:, None, :]              # (B,1,N+1)
-        logits, tails = M.verify(params, cfg, state_c, rows,
-                                 pos_off=topo.pos_off,
-                                 tail_mask=topo.anc_mask)
-        if spec.sampling:
-            # noise keyed per tree LEVEL (pos_off), so same-level nodes
-            # share it: alive nodes share prefixes -> logits -> samples,
-            # and the slot's sampled trajectory is well defined across the
-            # whole tree (duplicate-token siblings included)
-            preds_n = sample_predictions(logits, use_keys, s.temperature,
-                                         s.top_p, levels=topo.pos_off)[:, 0]
-        else:
-            preds_n = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-        # path views: (B, P, w) draft tokens / (B, P, w+1) predictions
-        drafts_pv = jnp.take(nodes, topo.path_nodes, axis=1)
-        greedy_pv = jnp.take(preds_n, topo.path_inputs, axis=1)
-        row_mask = None
+    # the step's three phases carry named scopes (spec.draft, spec.verify,
+    # spec.commit): they label the ops' metadata, so a device trace splits
+    # the step by phase; the computation is unchanged
+    with jax.named_scope("spec.draft"):
+        last = jnp.take_along_axis(buf_c, (len_c - 1)[:, None],
+                                   axis=1)[:, 0]
         if adaptive:
-            # a (width_b, depth_b) arm keeps exactly the paths whose branch
-            # indices all fall below width_b (NOT a prefix of the path
-            # list — eligibility is scattered through lex order)
-            row_mask = (jnp.asarray(topo.path_max_branch, jnp.int32)[None]
-                        < k_eff[:, None])
-        acc = accept(drafts_pv, greedy_pv, w_eff=w_eff, row_mask=row_mask)
-    else:
-        rows = jnp.concatenate(
-            [jnp.broadcast_to(last[:, None, None], (B, spec.k, 1)), drafts],
-            axis=-1)                                            # (B,k,w+1)
-        logits, tails = M.verify(params, cfg, state_c, rows)
-        if spec.sampling:
-            # noise keyed per position level and SHARED across the k rows:
-            # rows alive at level j have identical prefixes -> identical
-            # logits -> identical samples, so acceptance walks one sampled
-            # trajectory and the bonus is its first divergent (= residual)
-            # token — the point-mass rejection rule, lossless for any k
-            greedy = sample_predictions(logits, use_keys, s.temperature,
-                                        s.top_p)
+            # per-slot, per-step arm selection INSIDE the jit: UCB over the
+            # slot's own (B, A) stats, then mask the fixed (k_max, w_max)
+            # shapes down to the chosen arm — no recompile can ever occur
+            slow = (tree_arm_slowdowns(cfg, spec.arms, spec.tree_branch,
+                                       spec.adapt_ell) if spec.tree
+                    else arm_slowdowns(cfg, spec.arms, spec.adapt_ell))
+            arm = choose_arms(st, slow, spec.adapt_explore)     # (B,)
+            k_eff = jnp.asarray([a[0] for a in spec.arms], jnp.int32)[arm]
+            w_eff = jnp.asarray([a[1] for a in spec.arms], jnp.int32)[arm]
+            drafts, valid, n_ctx = _draft_adaptive(spec, tables, buf_c,
+                                                   len_c, last, arm)
         else:
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        acc = accept(drafts, greedy, k_eff=k_eff, w_eff=w_eff)
-    active = s.active & (~done_c) & (len_c - s.prompt_len < s.budget)
-    budget = jnp.maximum(s.prompt_len + s.budget - len_c, 0)
-    n_commit = jnp.where(active, jnp.minimum(acc.n_commit, budget), 0)
-    # eos truncation: commit only up to (and including) the first eos
-    iseos = (acc.tokens == s.eos_id[:, None]) & (s.eos_id >= 0)[:, None]
-    first_eos = jnp.argmax(iseos, axis=1)
-    has_eos = iseos.any(axis=1) & (first_eos < n_commit)
-    n_commit = jnp.where(has_eos, first_eos + 1, n_commit)
-    done_c = done_c | (has_eos & active)
-    # commit the model state
-    if spec.tree:
-        # gather the winning PATH's verify inputs out of the (N+1)-wide
-        # tree tails -> a (w+1)-wide linear tail, then the stock commit
-        # (winner row 0 of 1) writes it — linear AND paged paths unchanged
-        sel = jnp.asarray(topo.path_inputs, jnp.int32)[acc.winner]  # (B,w+1)
-        idx = sel[None, :, None, :, None, None]
-        tails = {g: {kk: jnp.take_along_axis(tt, idx, axis=3)
-                     for kk, tt in d.items()} for g, d in tails.items()}
-        state_n = M.commit_kv_tails(cfg, state_c, tails,
-                                    jnp.zeros((B,), jnp.int32), n_commit)
-    elif not M.has_recurrent(cfg):
-        state_n = M.commit_kv_tails(cfg, state_c, tails, acc.winner,
-                                    n_commit)
-    else:
-        row_tok = jnp.take_along_axis(
-            rows, acc.winner[:, None, None], axis=1)[:, 0]      # (B,w+1)
-        _, state_n = M.decode(params, cfg, state_c, row_tok,
-                              n_commit=n_commit)
-    # write accepted tokens into the buffer
-    pos = jnp.arange(spec.w + 1)[None, :]
-    slots = jnp.clip(len_c[:, None] + pos, 0, L - 1)
-    gate = pos < n_commit[:, None]
-    b_idx = jnp.broadcast_to(jnp.arange(B)[:, None], slots.shape)
-    old = buf_c[b_idx, slots]
-    buf_n = buf_c.at[b_idx, slots].set(
-        jnp.where(gate, acc.tokens, old))
-    len_n = len_c + n_commit
-    done_n = done_c | (len_n - s.prompt_len >= s.budget)
-    # ---- stats ----
-    st = dict(st)
-    st["calls"] = st["calls"] + active.astype(jnp.int32)
-    st["tokens"] = st["tokens"] + n_commit
-    st["accept_hist"] = st["accept_hist"].at[
-        jnp.arange(B), jnp.clip(n_commit, 0, spec.w + 1)].add(
-            active.astype(jnp.int32))
-    n_win = jnp.take_along_axis(acc.n_acc, acc.winner[:, None], 1)[:, 0]
-    st["rank_hist"] = st["rank_hist"].at[jnp.arange(B), acc.winner].add(
-        (active & (n_win > 0)).astype(jnp.int32))
-    st["alloc_ctx"] = st["alloc_ctx"].at[
-        jnp.arange(B), jnp.clip(n_ctx, 0, spec.k)].add(
-            active.astype(jnp.int32))
-    # winning path's origin: the drafter row its first branch tracks (tree)
-    # or the winning row itself (linear)
-    from_ctx = (jnp.asarray(topo.path_first, jnp.int32)[acc.winner] < n_ctx
-                if spec.tree else acc.winner < n_ctx)
-    acc_drafted = jnp.maximum(n_commit - 1, 0)
-    st["accepted_ctx"] = st["accepted_ctx"] + jnp.where(
-        active & from_ctx, acc_drafted, 0)
-    st["accepted_bigram"] = st["accepted_bigram"] + jnp.where(
-        active & ~from_ctx, acc_drafted, 0)
-    if adaptive:
-        # reward the pulled arm with the tokens its call committed (bonus
-        # included — the same tokens-per-call quantity AdaptiveKW tracks)
-        st = update_arm_stats(st, arm, n_commit, active, spec.adapt_ema)
-    return dataclasses.replace(s, buf=buf_n, buf_len=len_n, done=done_n,
-                               model=state_n, stats=st,
-                               rng_key=carry_keys)
+            arm = k_eff = w_eff = None
+            drafts, valid, n_ctx = _draft(spec, tables, buf_c, len_c, last)
+        if spec.tree:
+            nodes = T.fill_tree(topo, drafts, tables,
+                                buf=buf_c, buf_len=len_c)       # (B, N)
+    with jax.named_scope("spec.verify"):
+        if spec.tree:
+            # ONE (B, 1, N+1) verify call scores the whole token tree; the
+            # topology's ancestor mask + per-level positions make every
+            # root-to-leaf path bit-identical to a linear row of its tokens
+            rows = jnp.concatenate([last[:, None], nodes],
+                                   axis=1)[:, None, :]          # (B,1,N+1)
+            logits, tails = M.verify(params, cfg, state_c, rows,
+                                     pos_off=topo.pos_off,
+                                     tail_mask=topo.anc_mask)
+        else:
+            rows = jnp.concatenate(
+                [jnp.broadcast_to(last[:, None, None], (B, spec.k, 1)),
+                 drafts], axis=-1)                              # (B,k,w+1)
+            logits, tails = M.verify(params, cfg, state_c, rows)
+    with jax.named_scope("spec.commit"):
+        if spec.tree:
+            if spec.sampling:
+                # noise keyed per tree LEVEL (pos_off), so same-level
+                # nodes share it: alive nodes share prefixes -> logits ->
+                # samples, and the slot's sampled trajectory is well
+                # defined across the whole tree (duplicate-token siblings
+                # included)
+                preds_n = sample_predictions(
+                    logits, use_keys, s.temperature, s.top_p,
+                    levels=topo.pos_off)[:, 0]
+            else:
+                preds_n = jnp.argmax(logits[:, 0],
+                                     axis=-1).astype(jnp.int32)
+            # path views: (B, P, w) draft tokens / (B, P, w+1) predictions
+            drafts_pv = jnp.take(nodes, topo.path_nodes, axis=1)
+            greedy_pv = jnp.take(preds_n, topo.path_inputs, axis=1)
+            row_mask = None
+            if adaptive:
+                # a (width_b, depth_b) arm keeps exactly the paths whose
+                # branch indices all fall below width_b (NOT a prefix of
+                # the path list — eligibility is scattered through lex
+                # order)
+                row_mask = (jnp.asarray(topo.path_max_branch,
+                                        jnp.int32)[None] < k_eff[:, None])
+            acc = accept(drafts_pv, greedy_pv, w_eff=w_eff,
+                         row_mask=row_mask)
+        else:
+            if spec.sampling:
+                # noise keyed per position level and SHARED across the k
+                # rows: rows alive at level j have identical prefixes ->
+                # identical logits -> identical samples, so acceptance
+                # walks one sampled trajectory and the bonus is its first
+                # divergent (= residual) token — the point-mass rejection
+                # rule, lossless for any k
+                greedy = sample_predictions(logits, use_keys, s.temperature,
+                                            s.top_p)
+            else:
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            acc = accept(drafts, greedy, k_eff=k_eff, w_eff=w_eff)
+        active = s.active & (~done_c) & (len_c - s.prompt_len < s.budget)
+        budget = jnp.maximum(s.prompt_len + s.budget - len_c, 0)
+        n_commit = jnp.where(active, jnp.minimum(acc.n_commit, budget), 0)
+        # eos truncation: commit only up to (and including) the first eos
+        iseos = ((acc.tokens == s.eos_id[:, None])
+                 & (s.eos_id >= 0)[:, None])
+        first_eos = jnp.argmax(iseos, axis=1)
+        has_eos = iseos.any(axis=1) & (first_eos < n_commit)
+        n_commit = jnp.where(has_eos, first_eos + 1, n_commit)
+        done_c = done_c | (has_eos & active)
+        # commit the model state
+        if spec.tree:
+            # gather the winning PATH's verify inputs out of the
+            # (N+1)-wide tree tails -> a (w+1)-wide linear tail, then the
+            # stock commit (winner row 0 of 1) writes it — linear AND
+            # paged paths unchanged
+            sel = jnp.asarray(topo.path_inputs,
+                              jnp.int32)[acc.winner]            # (B,w+1)
+            idx = sel[None, :, None, :, None, None]
+            tails = {g: {kk: jnp.take_along_axis(tt, idx, axis=3)
+                         for kk, tt in d.items()}
+                     for g, d in tails.items()}
+            state_n = M.commit_kv_tails(cfg, state_c, tails,
+                                        jnp.zeros((B,), jnp.int32),
+                                        n_commit)
+        elif not M.has_recurrent(cfg):
+            state_n = M.commit_kv_tails(cfg, state_c, tails, acc.winner,
+                                        n_commit)
+        else:
+            row_tok = jnp.take_along_axis(
+                rows, acc.winner[:, None, None], axis=1)[:, 0]  # (B,w+1)
+            _, state_n = M.decode(params, cfg, state_c, row_tok,
+                                  n_commit=n_commit)
+        # write accepted tokens into the buffer
+        pos = jnp.arange(spec.w + 1)[None, :]
+        slots = jnp.clip(len_c[:, None] + pos, 0, L - 1)
+        gate = pos < n_commit[:, None]
+        b_idx = jnp.broadcast_to(jnp.arange(B)[:, None], slots.shape)
+        old = buf_c[b_idx, slots]
+        buf_n = buf_c.at[b_idx, slots].set(
+            jnp.where(gate, acc.tokens, old))
+        len_n = len_c + n_commit
+        done_n = done_c | (len_n - s.prompt_len >= s.budget)
+        # ---- stats ----
+        st = dict(st)
+        st["calls"] = st["calls"] + active.astype(jnp.int32)
+        st["tokens"] = st["tokens"] + n_commit
+        st["accept_hist"] = st["accept_hist"].at[
+            jnp.arange(B), jnp.clip(n_commit, 0, spec.w + 1)].add(
+                active.astype(jnp.int32))
+        n_win = jnp.take_along_axis(acc.n_acc, acc.winner[:, None],
+                                    1)[:, 0]
+        st["rank_hist"] = st["rank_hist"].at[
+            jnp.arange(B), acc.winner].add(
+                (active & (n_win > 0)).astype(jnp.int32))
+        st["alloc_ctx"] = st["alloc_ctx"].at[
+            jnp.arange(B), jnp.clip(n_ctx, 0, spec.k)].add(
+                active.astype(jnp.int32))
+        # winning path's origin: the drafter row its first branch tracks
+        # (tree) or the winning row itself (linear)
+        from_ctx = (jnp.asarray(topo.path_first, jnp.int32)[acc.winner]
+                    < n_ctx if spec.tree else acc.winner < n_ctx)
+        acc_drafted = jnp.maximum(n_commit - 1, 0)
+        st["accepted_ctx"] = st["accepted_ctx"] + jnp.where(
+            active & from_ctx, acc_drafted, 0)
+        st["accepted_bigram"] = st["accepted_bigram"] + jnp.where(
+            active & ~from_ctx, acc_drafted, 0)
+        if adaptive:
+            # reward the pulled arm with the tokens its call committed
+            # (bonus included — the same tokens-per-call quantity
+            # AdaptiveKW tracks)
+            st = update_arm_stats(st, arm, n_commit, active, spec.adapt_ema)
+        return dataclasses.replace(s, buf=buf_n, buf_len=len_n,
+                                   done=done_n, model=state_n, stats=st,
+                                   rng_key=carry_keys)
 
 
 def _greedy_body(params, cfg: ModelConfig, spec: SpecConfig,
@@ -765,40 +789,44 @@ def _greedy_body(params, cfg: ModelConfig, spec: SpecConfig,
         s = dataclasses.replace(
             s, model=C.grow_pages(s.model, s.model["cur_len"] + 1, act))
     buf_c, len_c, done_c, state_c = s.buf, s.buf_len, s.done, s.model
-    last = jnp.take_along_axis(buf_c, (len_c - 1)[:, None], axis=1)
-    logits, state_n = M.decode(params, cfg, state_c, last)
-    active = s.active & (~done_c) & (len_c - s.prompt_len < s.budget)
-    # decode advances cur_len by 1 for every row; freeze inactive rows so
-    # the cur_len == buf_len - 1 invariant holds for done/free slots too
-    # (their discarded cache/state writes are row-local and invisible:
-    # key_positions only exposes p < cur_len, and admission overwrites).
-    state_n = {**state_n,
-               "cur_len": state_c["cur_len"] + active.astype(jnp.int32)}
-    if spec.sampling:
-        nk = jax.vmap(jax.random.split)(s.rng_key)          # (B, 2, 2)
-        nxt = sample_token(logits[:, -1], nk[:, 0], s.temperature, s.top_p)
-        carry_keys = nk[:, 1]
-    else:
-        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        carry_keys = s.rng_key
-    slots = jnp.clip(len_c, 0, L - 1)
-    buf_n = buf_c.at[jnp.arange(B), slots].set(
-        jnp.where(active, nxt, buf_c[jnp.arange(B), slots]))
-    len_n = len_c + active.astype(jnp.int32)
-    done_n = done_c | (len_n - s.prompt_len >= s.budget)
-    done_n = done_n | ((nxt == s.eos_id) & (s.eos_id >= 0))
-    st = dict(s.stats)
-    st["calls"] = st["calls"] + active.astype(jnp.int32)
-    st["tokens"] = st["tokens"] + active.astype(jnp.int32)
-    # a greedy-body call commits exactly one token, so it lands in bin 1 of
-    # the shared n_commit histogram — keeping "hist.sum() == calls" true for
-    # every strategy, and bin 0 structurally zero engine-wide (see
-    # _init_stats: every step path commits >= 1 token per call)
-    st["accept_hist"] = st["accept_hist"].at[:, 1].add(
-        active.astype(jnp.int32))
-    return dataclasses.replace(s, buf=buf_n, buf_len=len_n, done=done_n,
-                               model=state_n, stats=st,
-                               rng_key=carry_keys)
+    # the same phase scopes as _spec_body: no drafts, one decode call
+    with jax.named_scope("spec.verify"):
+        last = jnp.take_along_axis(buf_c, (len_c - 1)[:, None], axis=1)
+        logits, state_n = M.decode(params, cfg, state_c, last)
+    with jax.named_scope("spec.commit"):
+        active = s.active & (~done_c) & (len_c - s.prompt_len < s.budget)
+        # decode advances cur_len by 1 for every row; freeze inactive rows so
+        # the cur_len == buf_len - 1 invariant holds for done/free slots too
+        # (their discarded cache/state writes are row-local and invisible:
+        # key_positions only exposes p < cur_len, and admission overwrites).
+        state_n = {**state_n,
+                   "cur_len": state_c["cur_len"] + active.astype(jnp.int32)}
+        if spec.sampling:
+            nk = jax.vmap(jax.random.split)(s.rng_key)          # (B, 2, 2)
+            nxt = sample_token(logits[:, -1], nk[:, 0], s.temperature,
+                               s.top_p)
+            carry_keys = nk[:, 1]
+        else:
+            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            carry_keys = s.rng_key
+        slots = jnp.clip(len_c, 0, L - 1)
+        buf_n = buf_c.at[jnp.arange(B), slots].set(
+            jnp.where(active, nxt, buf_c[jnp.arange(B), slots]))
+        len_n = len_c + active.astype(jnp.int32)
+        done_n = done_c | (len_n - s.prompt_len >= s.budget)
+        done_n = done_n | ((nxt == s.eos_id) & (s.eos_id >= 0))
+        st = dict(s.stats)
+        st["calls"] = st["calls"] + active.astype(jnp.int32)
+        st["tokens"] = st["tokens"] + active.astype(jnp.int32)
+        # a greedy-body call commits exactly one token, so it lands in bin 1 of
+        # the shared n_commit histogram — keeping "hist.sum() == calls" true for
+        # every strategy, and bin 0 structurally zero engine-wide (see
+        # _init_stats: every step path commits >= 1 token per call)
+        st["accept_hist"] = st["accept_hist"].at[:, 1].add(
+            active.astype(jnp.int32))
+        return dataclasses.replace(s, buf=buf_n, buf_len=len_n, done=done_n,
+                                   model=state_n, stats=st,
+                                   rng_key=carry_keys)
 
 
 def _step_body(params, cfg: ModelConfig, spec: SpecConfig,

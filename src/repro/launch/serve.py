@@ -135,6 +135,8 @@ def main() -> None:
                         page_size=args.page_size, mesh=mesh,
                         sampling=args.temperature > 0 or None,
                         seed=args.seed)
+    if eng.tables_s is not None:
+        print(f"drafter tables built in {eng.tables_s:.1f} s")
     for prompt, _ in make_prompts(args.task, args.n_prompts):
         eng.submit(prompt, max_new_tokens=args.max_new,
                    temperature=args.temperature, top_p=args.top_p)

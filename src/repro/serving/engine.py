@@ -37,11 +37,20 @@ those shardings pinned on inputs and outputs (donation + single-trace
 preserved), and the activation sharder is scoped to this engine's traces —
 never installed globally.  Outputs remain bit-identical to unsharded
 serving; ``mesh_report()`` shows what actually sharded.
+
+The continuous path marks its host work with ``jax.profiler`` spans, which
+record only while a profiler session is active: ``engine.step`` (one
+``step()``), inside it ``engine.done_wait`` (the done-flag readback),
+``engine.readback`` (a retire round's batched readback), ``engine.retire``
+and ``engine.admit`` (one per request, tagged with its ``request_id``) and
+``engine.dispatch`` (the ``spec_step`` call).  ``tables_s`` counts the
+seconds the constructor spent building the drafter's tables.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import time
 import warnings
 from typing import Dict, List, Optional, Tuple
@@ -49,6 +58,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 from jax.sharding import Mesh
 
 from ..core.ngram_tables import NGramTables, build_bigram, build_unigram
@@ -195,11 +205,18 @@ class ServingEngine:
                 f"(sliding_window=None, >=1 attn layer); run linear instead")
         self._paged_cfg = (PagedConfig(num_pages or 0, page_size)
                            if paged else None)
+        # seconds the constructor spent in build_tables, the compile or
+        # cache load of its forward included; None when the caller passed
+        # the tables in.  Host time: the forwards it dispatches may still
+        # be running on the device when it returns.
+        self.tables_s: Optional[float] = None
         if (self.spec.strategy != "greedy" or adaptive) and tables is None:
             arm_k = max((a[0] for a in self._arms or ()), default=0)
             arm_w = max((a[1] for a in self._arms or ()), default=0)
+            t0 = time.perf_counter()
             tables = self.build_tables(k_max=max(self.spec.k, 25, arm_k),
                                        w_max=max(self.spec.w, 16, arm_w))
+            self.tables_s = time.perf_counter() - t0
         self.tables = tables
         if mesh is not None and self.tables is not None:
             # draft tables are small integer lookups: replicate them
@@ -336,12 +353,9 @@ class ServingEngine:
             sample_args = tuple(
                 jax.device_put(a, shd.batch_sharding(self.mesh, a.shape))
                 for a in sample_args)
-        t0 = time.perf_counter()
         with self._act():
             buf, blen, stats = fn(self.params, tokens, eos, self.tables,
                                   *sample_args)
-        buf.block_until_ready()
-        dt = time.perf_counter() - t0
         if self.controller:
             self.controller.update(
                 kw, tokens=float(np.asarray(stats["tokens"]).sum()),
@@ -360,7 +374,6 @@ class ServingEngine:
                                              stats["calls"])[i])),
                 "accept_hist": np.asarray(stats["accept_hist"])[i].tolist()
                 if "accept_hist" in stats else [],
-                "wall_time_s": dt,
             }
         return batch.requests
 
@@ -469,6 +482,7 @@ class ServingEngine:
 
     # the three continuous-path device calls, routed through either the
     # module-level jits (mesh=None) or this engine's sharding-pinned jits
+    @functools.partial(annotate_function, name="engine.dispatch")
     def _run_step(self, state: DecodeState) -> DecodeState:
         with self._act():
             if self._step_jit is not None:
@@ -501,62 +515,67 @@ class ServingEngine:
         # The scheduler's one unavoidable per-step sync: slot reuse is a
         # host decision, so the done flags must come back every step.  The
         # ROADMAP's async-serving item replaces this with a lagged readback;
-        # until then it is THE baseline entry in BENCH_syncmap.json.
-        # repro-lint: allow(host-sync): scheduling branches on done flags host-side; async serving (ROADMAP) is the structural fix
-        done = np.asarray(state.done)
+        # until then it is the baseline entry of the host-sync inventory
+        # (`python -m repro.analysis --syncmap <file>` writes it).
+        with TraceAnnotation("engine.done_wait"):
+            # repro-lint: allow(host-sync): scheduling branches on done flags host-side; async serving (ROADMAP) is the structural fix
+            done = np.asarray(state.done)
         if not done[[s for s, _ in self._slots.occupied()]].any():
             return []
-        if self.paged:
-            # pool peak: occupancy only falls at release, so sampling here
-            # (before this round's frees) sees every high-water mark
-            # repro-lint: allow(host-sync): runs only on retire rounds, behind the done.any() gate — off the steady-state step path
-            in_use = self._pool_pages - int(np.asarray(state.model["free_top"]))
-            self._pool_peak = max(self._pool_peak, in_use)
-        # one device->host transfer per array, not per retired slot, and
-        # only on rounds that actually retire (behind the done.any() gate)
-        blen = np.asarray(state.buf_len)        # repro-lint: allow(host-sync): batched retire-round readback
-        plen = np.asarray(state.prompt_len)     # repro-lint: allow(host-sync): batched retire-round readback
-        buf = np.asarray(state.buf)             # repro-lint: allow(host-sync): batched retire-round readback
-        calls_np = np.asarray(state.stats["calls"])    # repro-lint: allow(host-sync): batched retire-round readback
-        tokens_np = np.asarray(state.stats["tokens"])  # repro-lint: allow(host-sync): batched retire-round readback
-        accept_hist_np = np.asarray(state.stats["accept_hist"])  # repro-lint: allow(host-sync): batched retire-round readback
-        arm_pulls_np = (np.asarray(state.stats["arm_pulls"])  # repro-lint: allow(host-sync): batched retire-round readback
-                        if self._arms else None)
+        with TraceAnnotation("engine.readback"):
+            if self.paged:
+                # pool peak: occupancy only falls at release, so sampling
+                # here (before this round's frees) sees every high-water mark
+                # repro-lint: allow(host-sync): runs only on retire rounds, behind the done.any() gate — off the steady-state step path
+                in_use = self._pool_pages - int(np.asarray(state.model["free_top"]))
+                self._pool_peak = max(self._pool_peak, in_use)
+            # one device->host transfer per array, not per retired slot,
+            # and only on rounds that actually retire (behind the
+            # done.any() gate)
+            blen = np.asarray(state.buf_len)        # repro-lint: allow(host-sync): batched retire-round readback
+            plen = np.asarray(state.prompt_len)     # repro-lint: allow(host-sync): batched retire-round readback
+            buf = np.asarray(state.buf)             # repro-lint: allow(host-sync): batched retire-round readback
+            calls_np = np.asarray(state.stats["calls"])    # repro-lint: allow(host-sync): batched retire-round readback
+            tokens_np = np.asarray(state.stats["tokens"])  # repro-lint: allow(host-sync): batched retire-round readback
+            accept_hist_np = np.asarray(state.stats["accept_hist"])  # repro-lint: allow(host-sync): batched retire-round readback
+            arm_pulls_np = (np.asarray(state.stats["arm_pulls"])  # repro-lint: allow(host-sync): batched retire-round readback
+                            if self._arms else None)
         retired: List[Request] = []
         for slot, req in self._slots.occupied():
             if not done[slot]:
                 continue
-            calls = int(calls_np[slot])
-            tokens = int(tokens_np[slot])
-            req.output_ids = buf[slot, plen[slot]:blen[slot]].copy()
-            req.output = self.tok.decode(req.output_ids)
-            req.stats = {
-                "new_tokens": int(blen[slot] - plen[slot]),
-                "model_calls": calls,
-                "tokens_per_call": float(tokens / max(1, calls)),
-                # this request's acceptance-length histogram: entry n =
-                # verify calls that committed exactly n tokens (0..w+1) —
-                # the paper's Fig. 4 ablation, per request (read BEFORE
-                # release zeroes the slot's stats rows)
-                # repro-lint: allow(host-sync): numpy-side tolist on the already-transferred accept_hist_np, not a device sync
-                "accept_hist": accept_hist_np[slot].tolist(),
-                # per-request admit->retire latency; deliberately NOT named
-                # wall_time_s (which in serve_all is the shared whole-batch
-                # generate time — a different quantity)
-                "latency_s": time.perf_counter() - req.stats["admit_t"],
-            }
-            if arm_pulls_np is not None:
-                # the slot's bandit history, read BEFORE release zeroes it
-                req.stats["arm_pulls"] = {
-                    self._arms[a]: int(arm_pulls_np[slot, a])
-                    for a in range(len(self._arms))
-                    if arm_pulls_np[slot, a]}
-                self._arm_pulls_total += arm_pulls_np[slot].astype(np.int64)
-            state = self._run_release(state, slot)
-            self._slots.release(slot)
-            if self.paged:
-                self._page_reserved.pop(slot, None)
-            retired.append(req)
+            with TraceAnnotation("engine.retire", request_id=req.request_id):
+                calls = int(calls_np[slot])
+                tokens = int(tokens_np[slot])
+                req.output_ids = buf[slot, plen[slot]:blen[slot]].copy()
+                req.output = self.tok.decode(req.output_ids)
+                req.stats = {
+                    "new_tokens": int(blen[slot] - plen[slot]),
+                    "model_calls": calls,
+                    "tokens_per_call": float(tokens / max(1, calls)),
+                    # this request's acceptance-length histogram: entry
+                    # n = verify calls that committed exactly n tokens
+                    # (0..w+1) — the paper's Fig. 4 ablation, per request
+                    # (read BEFORE release zeroes the slot's stats rows)
+                    # repro-lint: allow(host-sync): numpy-side tolist on the already-transferred accept_hist_np, not a device sync
+                    "accept_hist": accept_hist_np[slot].tolist(),
+                    # per-request admit->retire latency
+                    "latency_s": time.perf_counter() - req.stats["admit_t"],
+                }
+                if arm_pulls_np is not None:
+                    # the slot's bandit history, read BEFORE release
+                    # zeroes it
+                    req.stats["arm_pulls"] = {
+                        self._arms[a]: int(arm_pulls_np[slot, a])
+                        for a in range(len(self._arms))
+                        if arm_pulls_np[slot, a]}
+                    self._arm_pulls_total += arm_pulls_np[slot].astype(
+                        np.int64)
+                state = self._run_release(state, slot)
+                self._slots.release(slot)
+                if self.paged:
+                    self._page_reserved.pop(slot, None)
+                retired.append(req)
         self._cont_state = state
         return retired
 
@@ -640,20 +659,24 @@ class ServingEngine:
                     self._deferrals += 1
                     break
                 self._page_reserved[slot] = pages
-            self.scheduler.pop_next()
-            if mnt < req.max_new_tokens:
-                # static serve_all honours any budget (it sizes buffers per
-                # batch); the continuous DecodeState is sized once by
-                # max_new_cap, so an oversized request is clamped — loudly.
-                warnings.warn(
-                    f"request {req.request_id}: max_new_tokens "
-                    f"{req.max_new_tokens} exceeds the engine's continuous "
-                    f"max_new_cap={self.max_new_cap}; clamping (raise "
-                    f"max_new_cap to honour larger budgets)")
-            state = self._run_admit(state, slot, toks, mnt,
-                                    self._effective_eos(req), req)
-            self._slots.assign(slot, req)
-            req.stats = {"admit_t": time.perf_counter()}
+            with TraceAnnotation("engine.admit", request_id=req.request_id,
+                                 bucket=int(toks.shape[0])):
+                self.scheduler.pop_next()
+                if mnt < req.max_new_tokens:
+                    # static serve_all honours any budget (it sizes buffers
+                    # per batch); the continuous DecodeState is sized once
+                    # by max_new_cap, so an oversized request is clamped —
+                    # loudly.
+                    warnings.warn(
+                        f"request {req.request_id}: max_new_tokens "
+                        f"{req.max_new_tokens} exceeds the engine's "
+                        f"continuous max_new_cap={self.max_new_cap}; "
+                        f"clamping (raise "
+                        f"max_new_cap to honour larger budgets)")
+                state = self._run_admit(state, slot, toks, mnt,
+                                        self._effective_eos(req), req)
+                self._slots.assign(slot, req)
+                req.stats = {"admit_t": time.perf_counter()}
             i += 1
         self._cont_state = state
         return rejected
@@ -663,22 +686,25 @@ class ServingEngine:
         queued prompts into the freed slots, then run one jitted spec_step
         over every active slot.  Returns the requests completed this step —
         retired normally, or rejected at admission (``stats["error"]``)."""
-        if self._cont_state is None:
-            self._init_continuous()
-        retired = self._retire_finished()
-        retired.extend(self._admit_queued())
-        # occupancy is tracked host-side: after retirement every occupied
-        # slot is runnable (an admission that hit eos on its first token is
-        # retired next step; the one no-op spec_step it gets is rarer than
-        # paying a device->host sync on every step to detect it).
-        if len(self._slots):
-            self._cont_state = self._run_step(self._cont_state)
-            # peak-pool telemetry is NOT sampled here: reading free_top
-            # back every step was a per-step device->host sync on the
-            # decode critical path (repro-lint host-sync found it).  Pool
-            # occupancy only ever falls at release, so sampling it at
-            # retirement entry (before the frees) and in pool_stats()
-            # observes every high-water mark syncs-free on the hot path.
+        with TraceAnnotation("engine.step"):
+            if self._cont_state is None:
+                self._init_continuous()
+            retired = self._retire_finished()
+            retired.extend(self._admit_queued())
+            # occupancy is tracked host-side: after retirement every
+            # occupied slot is runnable (an admission that hit eos on its
+            # first token is retired next step; the one no-op spec_step it
+            # gets is rarer than paying a device->host sync on every step
+            # to detect it).
+            if len(self._slots):
+                self._cont_state = self._run_step(self._cont_state)
+                # peak-pool telemetry is NOT sampled here: reading free_top
+                # back every step was a per-step device->host sync on the
+                # decode critical path (repro-lint host-sync found it).
+                # Pool occupancy only ever falls at release, so sampling it
+                # at retirement entry (before the frees) and in
+                # pool_stats() observes every high-water mark syncs-free on
+                # the hot path.
         return retired
 
     def reset_pool_counters(self) -> None:
