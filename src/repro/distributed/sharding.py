@@ -244,6 +244,25 @@ def _batch_axes(mesh: Mesh, b: int):
     return resolve_axis(mesh, "embed", b, warn=False)
 
 
+def kv_cache_pspec(mesh: Mesh, shape: Tuple[int, ...]) -> P:
+    """PartitionSpec of a linear KV cache leaf (R, B, S, KV, hd)."""
+    _, B, S, KV, _ = shape
+    batch = _batch_axes(mesh, B)
+    kv_ax = resolve_axis(mesh, "kv", KV, warn=False)   # seq fallback below
+    seq_ax = None
+    if kv_ax is None and S % mesh.shape.get("model", 1) == 0:
+        # kv heads don't divide the model axis (kv=8/2/1 GQA): shard the
+        # cache SEQUENCE over "model" instead — attention contracts hd
+        # (replicated) and softmaxes over the sharded seq with small
+        # partial-reduce collectives.  Sharding hd instead forces an
+        # all-reduce of full (.., S) logits per layer (§Perf it-5).
+        seq_ax = "model"
+    if batch is None and seq_ax is None:
+        # batch=1 long-context: shard the cache sequence over "data"
+        seq_ax = "data" if S % mesh.shape.get("data", 1) == 0 else None
+    return P(None, batch, seq_ax, kv_ax, None)
+
+
 def state_pspec(mesh: Mesh, path, leaf) -> P:
     names = _path_names(path)
     name = names[-1]
@@ -253,20 +272,7 @@ def state_pspec(mesh: Mesh, path, leaf) -> P:
     R, B = shape[0], shape[1]
     batch = _batch_axes(mesh, B)
     if name in ("k", "v"):                      # (R, B, S, KV, hd)
-        _, _, S, KV, hd = shape
-        kv_ax = resolve_axis(mesh, "kv", KV, warn=False)   # seq fallback below
-        seq_ax = None
-        if kv_ax is None and S % mesh.shape.get("model", 1) == 0:
-            # kv heads don't divide the model axis (kv=8/2/1 GQA): shard the
-            # cache SEQUENCE over "model" instead — attention contracts hd
-            # (replicated) and softmaxes over the sharded seq with small
-            # partial-reduce collectives.  Sharding hd instead forces an
-            # all-reduce of full (.., S) logits per layer (§Perf it-5).
-            seq_ax = "model"
-        if batch is None and seq_ax is None:
-            # batch=1 long-context: shard the cache sequence over "data"
-            seq_ax = "data" if S % mesh.shape.get("data", 1) == 0 else None
-        return P(None, batch, seq_ax, kv_ax, None)
+        return kv_cache_pspec(mesh, shape)
     if name == "conv":                          # (R, B, dc-1, di)
         return P(None, batch, None, resolve_axis(mesh, "ffn", shape[-1]))
     if name == "ssm":                           # (R, B, di, ds)

@@ -11,8 +11,9 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .cache import (group_ids, init_state, is_paged, key_positions, kv_write,
-                    paged_dims, paged_kv_write, phys_slots, write_slots)
+from .cache import (group_ids, init_state, is_paged, key_positions,
+                    kv_commit_in_place, kv_write, paged_dims, paged_kv_write,
+                    phys_slots, write_slots)
 from .config import ATTN, MROPE, ModelConfig, layer_blocks
 from .layers import apply_norm, embed_tokens, lm_logits
 from .transformer import init_params, run_stack
@@ -203,8 +204,15 @@ def verify(params: Params, cfg: ModelConfig, state: State,
 def commit_kv_tails(cfg: ModelConfig, state: State, kv_tails: Dict,
                     winner: jnp.ndarray, n_commit: jnp.ndarray) -> State:
     """Fast commit for attention-only archs: write the winning row's accepted
-    KV tail into the shared cache (no replay forward needed).  Paged states
-    route the same gated write through each slot's page table."""
+    KV tail into the shared cache (no replay forward needed).
+
+    Linear caches write it in place (``kv_commit_in_place``).  The gated
+    scatter stays where that cannot work: ring caches (a tail may wrap past
+    the end of the ring) and caches whose slot or sequence dim the active
+    mesh splits (``act_sharding.splits_cache_rows``: a per-row window there
+    makes GSPMD all-gather the cache).  Paged states route the scatter
+    through each slot's page table."""
+    from ..distributed import act_sharding
     cur = state["cur_len"]
     groups = dict(state["groups"])
     paged = is_paged(state)
@@ -214,12 +222,19 @@ def commit_kv_tails(cfg: ModelConfig, state: State, kv_tails: Dict,
     else:
         gid0 = next(gid for gid, s, _ in group_ids(cfg) if s.mixer == ATTN)
         S = state["groups"][gid0]["k"].shape[2]
+    ring = cfg.sliding_window is not None and cfg.sliding_window <= S
     for gid, tails in kv_tails.items():
         k_t, v_t = tails["k_tail"], tails["v_tail"]  # (R,B,K,W1,KV,hd)
         R, B, K, W1 = k_t.shape[:4]
         wsel = winner.reshape(1, B, 1, 1, 1, 1)
         k_w = jnp.take_along_axis(k_t, wsel, axis=2)[:, :, 0]  # (R,B,W1,KV,hd)
         v_w = jnp.take_along_axis(v_t, wsel, axis=2)[:, :, 0]
+        kc, vc = state["groups"][gid]["k"], state["groups"][gid]["v"]
+        if not (paged or ring or W1 > S
+                or act_sharding.splits_cache_rows(kc.shape)):
+            kc, vc = kv_commit_in_place(kc, vc, k_w, v_w, cur, n_commit)
+            groups[gid] = {"k": kc, "v": vc}
+            continue
         slots = write_slots(cfg, S, cur, W1)
         gate = jnp.arange(W1)[None, :] < n_commit[:, None]
         if paged:

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.distributed import act_sharding
 from repro.models import model as M
 from repro.models.config import BlockSpec, ModelConfig
 
@@ -105,6 +106,86 @@ def test_commit_kv_tails_matches_replay(tiny_dense):
     lB, _ = M.decode(params, cfg, sB, nxt)
     np.testing.assert_allclose(np.asarray(lA), np.asarray(lB),
                                rtol=1e-5, atol=1e-5)
+
+
+# (buffer length, cur_len, n_commit, winner) per row; W1 = 4.  A buffer of
+# whole 128-position tiles (384) commits through 256-position windows, one
+# of 40 through 16-position windows aligned to 8, one of 16 through its
+# whole row
+COMMIT_CASES = {
+    "n0": (16, [0, 3, 7, 12], [0, 0, 0, 0], [0, 1, 2, 0]),
+    "n1": (16, [0, 3, 7, 12], [1, 1, 1, 1], [0, 1, 2, 0]),
+    "nW1": (16, [0, 3, 7, 12], [4, 4, 4, 4], [0, 1, 2, 0]),
+    "mixed-winners": (16, [2, 5, 9, 1], [4, 2, 3, 1], [2, 0, 1, 2]),
+    "b1": (16, [5], [3], [1]),
+    "past-end": (16, [13, 12, 15, 14], [4, 4, 4, 2], [0, 1, 2, 1]),
+    "inactive": (16, [3, 10, 0, 6], [3, 0, 0, 4], [1, 2, 0, 0]),
+    "tiles": (384, [126, 255, 0, 300], [4, 3, 4, 2], [2, 0, 1, 2]),
+    "tiles-past-end": (384, [381, 380, 384, 130], [4, 4, 0, 0],
+                       [1, 2, 0, 1]),
+    "small-tiles": (40, [37, 30, 8, 0], [4, 4, 3, 0], [0, 1, 2, 1]),
+    "ring": (16, [6, 10, 3, 13], [4, 3, 4, 1], [1, 0, 2, 2]),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", list(COMMIT_CASES))
+def test_commit_kv_tails_matches_scatter(case, dtype, monkeypatch):
+    """The commit equals the gated scatter bit for bit: positions cur ..
+    cur + n_commit - 1 of each row take the winner's tail, positions past
+    the buffer are dropped (linear) or wrap (ring, 8 slots), everything
+    else is left as it was.  Linear caches write in place (no scatter in
+    the program), or scatter where a mesh splits their rows
+    (``act_sharding.splits_cache_rows``); ring caches always scatter."""
+    S, *rows = COMMIT_CASES[case]
+    cur, n, win = (jnp.asarray(x, jnp.int32) for x in rows)
+    ring = case == "ring"
+    B, K, W1 = cur.shape[0], 3, 4
+    cfg = ModelConfig(name="commit", num_layers=3, d_model=32, num_heads=4,
+                      num_kv_heads=2, d_ff=64, vocab_size=61,
+                      param_dtype=dtype, compute_dtype=dtype,
+                      sliding_window=8 if ring else None,
+                      prefix_blocks=(BlockSpec("attn", "swiglu"),)
+                      ).validate()
+    state = M.init_state(cfg, B, S)
+    keys = iter(jax.random.split(jax.random.PRNGKey(3), 16))
+    rand = lambda shape: jax.random.normal(next(keys), shape).astype(dtype)
+    state["groups"] = {g: {kk: rand(c.shape) for kk, c in d.items()}
+                       for g, d in state["groups"].items()}
+    state["cur_len"] = cur
+    tails = {g: {f"{kk}_tail": rand(c.shape[:2] + (K, W1) + c.shape[3:])
+                 for kk, c in d.items()}
+             for g, d in state["groups"].items()}
+    want = {}
+    for g, d in state["groups"].items():
+        for kk, c in d.items():
+            c = np.array(c)
+            t = np.asarray(tails[g][f"{kk}_tail"])
+            S = c.shape[2]
+            for b in range(B):
+                for j in range(int(n[b])):
+                    p = int(cur[b]) + j
+                    if ring:
+                        p %= S
+                    elif p >= S:
+                        continue
+                    c[:, b, p] = t[:, b, int(win[b]), j]
+            want[g, kk] = c
+
+    for in_place in ((True,) if ring else (True, False)):
+        monkeypatch.setattr(act_sharding, "splits_cache_rows",
+                            lambda shape, split=not in_place: split)
+        fn = jax.jit(lambda st, t, w, nc: M.commit_kv_tails(
+            cfg, st, t, w, nc))
+        got = fn(state, tails, win, n)
+        np.testing.assert_array_equal(np.asarray(got["cur_len"]),
+                                      np.asarray(cur + n))
+        for (g, kk), c in want.items():
+            assert np.array_equal(np.asarray(got["groups"][g][kk]), c), \
+                (case, in_place, g, kk)
+        jaxpr = str(jax.make_jaxpr(fn)(state, tails, win, n))
+        assert ("scatter" in jaxpr) == (ring or not in_place), in_place
 
 
 def test_encoder_only_forward():

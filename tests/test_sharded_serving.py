@@ -19,6 +19,7 @@ OWN process (the CI ``sharded`` lane):
 Under the plain tier-1 run (1 CPU device) everything here skips.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -149,6 +150,50 @@ def test_adaptive_sharded_parity(model, tables, mesh22):
     _assert_parity(plain, meshed)
     for r in meshed:
         assert sum(r.stats["arm_pulls"].values()) == r.stats["model_calls"]
+
+
+# ---------------------------------------------------------------------------
+# the speculative commit under a mesh: in place where every device holds
+# whole cache rows, the scatter where the slot or sequence dim is split
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("mode", ["continuous", "static"])
+@pytest.mark.parametrize("shape,in_place", [((1, 2), True),
+                                            ((1, 4), False),
+                                            ((2, 2), False)],
+                         ids=["kv-heads", "sequence", "slots"])
+def test_commit_moves_no_whole_cache(model, tables, shape, in_place, mode):
+    """kv=2 heads over "model" of (1, 2) leave slot and sequence whole: the
+    commit writes in place.  On (1, 4) the sequence takes "model" (kv=2
+    does not divide 4; 16 + 19 + w + 2 = 40 positions do), on (2, 2) the
+    slots take "data": a per-row window there would all-gather the cache,
+    so the commit scatters.  Either way no collective of the commit
+    carries a whole cache, and the outputs are the unsharded engine's, in
+    the continuous step and in one-shot ``generate()`` alike."""
+    cfg, params = model
+    spec = _spec("mixed")
+    mesh = make_debug_mesh(shape)
+    engines = [ServingEngine(params, cfg, spec, tables=tables, max_batch=4,
+                             buckets=(16,), max_new_cap=19, mesh=m)
+               for m in (None, mesh)]
+    prompts = [(p, 19) for p, _ in PROMPTS[:4]]
+    _assert_parity(*(_serve(e, mode, prompts) for e in engines))
+    R, B, S = 2, 4, 16 + 19 + spec.w + 2       # layers, slots, positions
+    eng = engines[1]
+    if mode == "continuous":
+        hlo = eng.step_hlo()
+    else:
+        [((max_new, _, _), gen)] = eng._gen_cache.items()
+        assert max_new == 19
+        rows = lambda x: jax.device_put(x, shd.batch_sharding(mesh, x.shape))
+        with act_sharding.activated(mesh):
+            hlo = gen.lower(eng.params, rows(jnp.zeros((B, 16), jnp.int32)),
+                            rows(jnp.zeros((B,), jnp.int32)),
+                            eng.tables).compile().as_text()
+    whole = re.compile(rf"=\s*\(?f32\[{R},{B},{S},.*\s(all-gather|all-to-all"
+                       rf"|all-reduce|collective-permute)(-start)?\(")
+    assert not [line for line in hlo.splitlines()
+                if whole.search(line) and "spec.commit" in line]
+    assert ("spec.commit/vmap()/scatter" in hlo) == (not in_place)
 
 
 # ---------------------------------------------------------------------------

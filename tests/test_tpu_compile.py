@@ -7,13 +7,16 @@ not read, more VMEM than a kernel may use.  Interpret mode, which the rest
 of the suite uses, checks none of that.
 
 Shapes are StableLM-2-1.6B's at serving widths (bf16, 32 heads, 32 KV
-heads, head_dim 64; 8 slots of 2048 positions; cache block 512).
+heads, head_dim 64; 8 slots of 2048 positions; cache block 512).  The
+speculative commit is compiled at the served shapes of the benchmark's
+decode-b4 cell (4 slots of 1024 positions), alone and inside the step.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,11 +24,15 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import spec_engine as E
 from repro.core import tree as T
+from repro.core.ngram_tables import abstract_tables
 from repro.kernels import dispatch, ops
 from repro.kernels.ngram_match import LANE, TILE, ngram_match_call
 from repro.kernels.spec_attention import (paged_spec_attention_call,
                                           spec_attention_call)
+from repro.models import model as M
+from repro.models.config import BlockSpec, ModelConfig
 
 B, H, KV, HD, S, BLOCK_S = 8, 32, 32, 64, 2048, 512
 DT = jnp.bfloat16
@@ -120,3 +127,91 @@ def test_ngram_match_call_compiles(one_chip):
     hlo = _compile(fn, s((B, rows, LANE), jnp.int32), s((B, 1), jnp.int32),
                    s((B,), jnp.int32))
     assert dispatch.kernels_in_hlo(hlo) == ("ngram_match",)
+
+
+# ---------------------------------------------------------------------------
+# the speculative commit: the KV cache is updated in place
+# ---------------------------------------------------------------------------
+def _stablelm(layers):
+    """StableLM-2-1.6B's widths (no q/k/v bias), ``layers`` deep."""
+    return ModelConfig(name=f"stablelm-2-1.6b-l{layers}", num_layers=layers,
+                       d_model=2048, num_heads=32, num_kv_heads=32,
+                       head_dim=64, d_ff=5632, vocab_size=100352,
+                       block_pattern=(BlockSpec("attn", "swiglu"),),
+                       norm="layernorm", partial_rotary_factor=0.25,
+                       backend="pallas", param_dtype=DT,
+                       compute_dtype=DT).validate()
+
+
+def _on_chip(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _cache_copies(hlo, shape):
+    """Copies (sync or async) whose result has the cache leaf's shape."""
+    pat = re.compile(r"=\s*\(?" + re.escape(shape)
+                     + r"\{.*\s(copy|copy-start)\(")
+    return [line.strip()[:120] for line in hlo.splitlines()
+            if pat.search(line)]
+
+
+def _assert_cache_aliased(hlo, out_tree, arg):
+    """Every K/V leaf of the output aliases the donated input it came from
+    (the entry parameter named ``arg`` + the leaf's path)."""
+    alias = {int(o): int(p) for o, p in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}, may-alias\)", hlo.splitlines()[0])}
+    params = {}
+    for line in hlo.splitlines():
+        m = re.search(r'parameter\((\d+)\).*op_name="([^"]*)"',
+                      line.replace("\\'", "'"))
+        if m:
+            params[m.group(2)] = int(m.group(1))
+    flat = jax.tree_util.tree_flatten_with_path(out_tree)[0]
+    leaves = [(i, arg + jax.tree_util.keystr(path))
+              for i, (path, _) in enumerate(flat)
+              if jax.tree_util.keystr(path).endswith(("['k']", "['v']"))]
+    assert leaves
+    for i, name in leaves:
+        assert alias.get(i) == params[name], (name, i, alias.get(i))
+
+
+def test_commit_updates_the_cache_in_place(one_chip):
+    """decode-b4's commit alone (24 layers, 4 slots of 1024, k=10, w=10):
+    no whole-cache copy, and K and V alias the donated state."""
+    R, B, S, K, W1 = 24, 4, 1024, 10, 11
+    cfg = _stablelm(R)
+    state = _on_chip(jax.eval_shape(lambda: M.init_state(cfg, B, S)),
+                     one_chip)
+    tails = {g: {f"{kk}_tail": jax.ShapeDtypeStruct(
+                 c.shape[:2] + (K, W1) + c.shape[3:], c.dtype,
+                 sharding=one_chip) for kk, c in d.items()}
+             for g, d in state["groups"].items()}
+    rows = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    fn = jax.jit(lambda st, t, w, n: M.commit_kv_tails(cfg, st, t, w, n),
+                 donate_argnums=0)
+    hlo = fn.lower(state, tails, rows, rows).compile().as_text()
+    assert _cache_copies(hlo, f"bf16[{R},{B},{S},32,64]") == []
+    _assert_cache_aliased(hlo, state, "st")
+
+
+def test_spec_step_updates_the_cache_in_place(one_chip, monkeypatch):
+    """The whole served step, both kernels compiled for the chip, at
+    StableLM widths cut to 2 layers (the smallest cache, where XLA is the
+    most ready to relayout it): no whole-cache copy, K and V aliased."""
+    monkeypatch.setattr(dispatch, "default_interpret", lambda: False)
+    R, B, S = 2, 4, 1024
+    cfg = _stablelm(R)
+    spec = E.SpecConfig(backend="pallas")
+    params = _on_chip(jax.eval_shape(
+        lambda: M.init_params(jax.random.PRNGKey(0), cfg)), one_chip)
+    state = _on_chip(jax.eval_shape(
+        lambda: E.empty_decode_state(cfg, spec, B, S)), one_chip)
+    tables = _on_chip(abstract_tables(cfg.vocab_size, spec.k, spec.w),
+                      one_chip)
+    hlo = E.spec_step.lower(params, cfg, spec, state,
+                            tables).compile().as_text()
+    assert dispatch.kernels_in_hlo(hlo) == ("ngram_match", "spec_attention")
+    assert _cache_copies(hlo, f"bf16[{R},{B},{S},32,64]") == []
+    _assert_cache_aliased(hlo, state, "state")
