@@ -10,7 +10,7 @@ positions.  The bounds can understate work, never overstate it.
 """
 from __future__ import annotations
 
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 import numpy as np
 
@@ -19,9 +19,22 @@ def bucket_of(n: int, buckets) -> int:
     return min((b for b in buckets if b >= n), default=max(buckets))
 
 
+def window(run) -> Tuple[float, float]:
+    """The window on the benchmark's clock.  In a traced run, the traced
+    part of it: from the start of its trace to the end of the part that
+    every chip's trace holds (``tracing.kept_end``), so that work counted
+    here and time read from the trace cover the same steps."""
+    if run.trace:
+        return run.trace_t0, min(run.t1, run.trace_t0
+                                 + run.trace["window_s"])
+    return run.t0, run.t1
+
+
 def window_steps(run) -> List[int]:
-    """Steps dispatched inside the window."""
-    return [s for s, t in enumerate(run.step_times) if run.t0 < t <= run.t1]
+    """Steps that returned inside the window (the traced part of it, in a
+    traced run)."""
+    lo, hi = window(run)
+    return [s for s, t in enumerate(run.step_times) if lo < t <= hi]
 
 
 def live_by_step(run) -> Dict[int, List[int]]:

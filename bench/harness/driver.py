@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Dict, Iterator, List, Optional
+from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -111,30 +111,46 @@ def warm_up(driver: Driver, warmup: List[Request],
     driver.step_times.clear()
 
 
+Mark = Optional[Tuple[float, Callable[[], None]]]
+
+
+def _at_mark(mark: Mark, t0: float, now: float) -> Mark:
+    """Run ``mark`` = (seconds into the window, fn) once it is due; returns
+    what is left to run."""
+    if mark is not None and now >= t0 + mark[0]:
+        mark[1]()
+        return None
+    return mark
+
+
 def run_closed(driver: Driver, reqs: Iterator[Request], clients: int,
-               seconds: float, on_open: Callable[[], None]) -> tuple:
+               seconds: float, on_open: Callable[[], None],
+               mark: Mark = None) -> tuple:
     """``clients`` clients, each sending its next request as soon as its
     previous one is back.  The first requests are submitted and admitted
     (one step) before the window opens; ``on_open`` runs just before it
-    does.  Returns (t0, t1)."""
+    does, and ``mark`` (seconds into the window, fn) before the first step
+    due at or after that time.  Returns (t0, t1)."""
     for _ in range(clients):
         driver.submit(next(reqs))
     driver.step()
     on_open()
     t0 = time.perf_counter()
     t1 = t0 + seconds
-    while time.perf_counter() < t1:
+    while (now := time.perf_counter()) < t1:
+        mark = _at_mark(mark, t0, now)
         for _ in driver.step():
             driver.submit(next(reqs))
     return t0, time.perf_counter()
 
 
 def run_open(driver: Driver, reqs: List[Request], seconds: float,
-             on_open: Callable[[], None]) -> tuple:
+             on_open: Callable[[], None], mark: Mark = None) -> tuple:
     """Submit each request at its scheduled time (offset from the window's
     start, which follows ``on_open``), stepping the engine whenever it has
-    work.  Requests due in the window but not yet sent when it closes are
-    sent then, so that the drain serves them.  Returns (t0, t1)."""
+    work; ``mark`` as for ``run_closed``.  Requests due in the window but
+    not yet sent when it closes are sent then, so that the drain serves
+    them.  Returns (t0, t1)."""
     on_open()
     t0 = time.perf_counter()
     t1 = t0 + seconds
@@ -143,6 +159,7 @@ def run_open(driver: Driver, reqs: List[Request], seconds: float,
         now = time.perf_counter()
         if now >= t1:
             break
+        mark = _at_mark(mark, t0, now)
         while i < n and t0 + reqs[i].scheduled <= now:
             driver.submit(reqs[i], scheduled=t0 + reqs[i].scheduled)
             i += 1
@@ -150,6 +167,8 @@ def run_open(driver: Driver, reqs: List[Request], seconds: float,
             driver.step()
         else:
             nxt = t0 + reqs[i].scheduled if i < n else t1
+            if mark is not None:
+                nxt = min(nxt, t0 + mark[0])
             time.sleep(max(0.0, min(nxt, t1) - now))
     end = time.perf_counter()
     for r in reqs[i:]:

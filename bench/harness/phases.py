@@ -11,7 +11,8 @@ plane, on the device ops' clock (``engine.step``, ``engine.done_wait``,
 the protobuf wire format.
 
 This sits beside ``tracing.py`` and leaves its reduction as it is; as
-there, only events inside the ``bench.window`` span count.
+there, only events inside the ``bench.window`` span count, up to the end of
+the part of it that every chip's plane holds (``tracing.kept_end``).
 """
 from __future__ import annotations
 
@@ -78,7 +79,12 @@ def reduce_profile(pd, raw: bytes) -> Dict:
         raise ValueError(f"no {tracing.WINDOW_SPAN} span in the trace")
     if not devices:
         raise ValueError("no TPU device plane in the trace")
-    lo, hi = window
+    lo, span_hi = window
+    lines = [[tracing._Ops(line, lo, span_hi) for line in plane.lines
+              if line.name == "XLA Ops"] for plane in devices]
+    hi = tracing.kept_end(lines, lo, span_hi,
+                          [(a, b) for a, b, n in host
+                           if n == tracing.STEP_SPAN])[0]
     spans = collections.defaultdict(lambda: {"s": 0.0, "n": 0})
     for a, b, name in host:
         if name.startswith(ENGINE) and lo <= a < hi:
@@ -90,7 +96,7 @@ def reduce_profile(pd, raw: bytes) -> Dict:
     scopes = collections.Counter()
     idle = collections.Counter()
     gaps = []
-    for plane in devices:
+    for plane, chip in zip(devices, lines):
         execs, programs = [], set()
         for line in plane.lines:
             if line.name != "XLA Modules":
@@ -104,12 +110,11 @@ def reduce_profile(pd, raw: bytes) -> Dict:
                     step["n"] += 1
         named = {name: scope_of(op) for (name, program), op
                  in tf_ops.get(plane.name, {}).items() if program in programs}
-        for line in plane.lines:
-            if line.name != "XLA Ops":
-                continue
-            _, g = tracing._ops_line(line, lo, hi, collections.Counter(),
-                                     collections.defaultdict(
-                                         lambda: {"s": 0.0, "n": 0}))
+        for line, ops in zip((x for x in plane.lines
+                              if x.name == "XLA Ops"), chip):
+            _, g, _ = ops.fold(lo, hi, collections.Counter(),
+                               collections.defaultdict(
+                                   lambda: {"s": 0.0, "n": 0}))
             gaps += g
             _idle_by_piece(pieces, g, idle)
             _scopes_line(line, sorted(execs), named, scopes)

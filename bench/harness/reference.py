@@ -1,30 +1,30 @@
 """The plain reference: the configuration's forward pass in float32.
 
 Straight ``jax.numpy`` at ``Precision.HIGHEST`` on every product, with no
-cache, no kernel and no batching trick: embedding, then per layer a norm,
-causal attention (grouped key/value heads, rotary embedding on the first
-``rotary`` dimensions of each head in the half-split layout, an optional
-sliding window), a norm and a SwiGLU MLP, each added to the residual; a
-final norm and the output head.  It imports nothing of the program and
-reads only the benchmark's own weights (``model.make_weights``).  It runs
-layer by layer, a few rows at a time, so that it fits beside the weights.
+cache, no kernel and no batching trick: the embedding, the configuration's
+layers one by one, and the output head, each as its family module
+(``bench/families/<family>.py``) writes them with the building blocks
+below.  It imports nothing of the program and reads only the benchmark's
+own weights (``model.make_weights``), sharded or not: it runs layer
+by layer, a few rows at a time, so that it fits beside the weights, and no
+chip holds a float32 copy of more than about one layer.
 
-``quant=True`` is the control: the same forward with both operands of
-every weight product rounded to float8 (e4m3, one scale per row of
-activations and per output column of weights) -- the precision one step
-below the bfloat16 the configurations state.
+``quant=True`` is the control: the same forward computed in float8 (e4m3)
+wherever the program computes in the bfloat16 the configurations state --
+both operands of every weight product (one scale per row of activations
+and per output column of weights), and the activations the program holds
+in that type between them, the residual stream and the attention's
+queries, keys and values (one scale per row; ``act``).
 """
 from __future__ import annotations
 
 import functools
 import json
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from .model import Dims
 
 HI = jax.lax.Precision.HIGHEST
 F8 = jnp.float8_e4m3fn
@@ -38,7 +38,13 @@ def _fp8(x, axis):
     return (x / s).astype(F8).astype(jnp.float32) * s
 
 
-def _mm(x, w, quant: bool):
+def act(x, quant: bool):
+    """An activation as the control holds it: rounded to float8 along its
+    last axis; unchanged in the reference."""
+    return _fp8(x, -1) if quant else x
+
+
+def mm(x, w, quant: bool):
     """x (..., k) @ w (k, n) in float32; float8 operands for the control."""
     w = w.astype(jnp.float32)
     if quant:
@@ -46,23 +52,24 @@ def _mm(x, w, quant: bool):
     return jnp.einsum("...k,kn->...n", x, w, precision=HI)
 
 
-def _norm(x, w, b, m: Dims):
-    if m.layernorm:
+def norm(x, w, b, eps: float):
+    """LayerNorm with bias ``b``, or RMSNorm where ``b`` is None."""
+    if b is not None:
         mu = x.mean(-1, keepdims=True)
         var = ((x - mu) ** 2).mean(-1, keepdims=True)
-        y = (x - mu) / jnp.sqrt(var + m.eps)
+        y = (x - mu) / jnp.sqrt(var + eps)
         return y * w.astype(jnp.float32) + b.astype(jnp.float32)
-    y = x / jnp.sqrt((x * x).mean(-1, keepdims=True) + m.eps)
+    y = x / jnp.sqrt((x * x).mean(-1, keepdims=True) + eps)
     return y * w.astype(jnp.float32)
 
 
-def _rope(x, m: Dims):
-    """x (B, T, N, hd): rotate the first ``m.rotary`` dims, half-split."""
-    r = m.rotary
+def rope(x, rotary: int, theta: float):
+    """x (B, T, N, hd): rotate the first ``rotary`` dims, half-split."""
+    r = rotary
     if r == 0:
         return x
     T = x.shape[1]
-    inv = 1.0 / (m.theta ** (np.arange(0, r, 2, dtype=np.float64) / r))
+    inv = 1.0 / (theta ** (np.arange(0, r, 2, dtype=np.float64) / r))
     ang = np.arange(T, dtype=np.float64)[:, None] * inv[None, :]
     cos = jnp.asarray(np.cos(ang), jnp.float32)[None, :, None, :]
     sin = jnp.asarray(np.sin(ang), jnp.float32)[None, :, None, :]
@@ -71,58 +78,49 @@ def _rope(x, m: Dims):
                            -1)
 
 
-def _layer(x, w, i, m: Dims, quant: bool):
-    """One decoder layer ``i`` of the stacked weights ``w``."""
-    B, T, _ = x.shape
-    g = lambda n: w[n][i]
-    b = (lambda n: g(n)) if m.layernorm else (lambda n: None)
-    h = _norm(x, g("ln1_w"), b("ln1_b"), m)
-    q = _rope(_mm(h, g("wq"), quant).reshape(B, T, m.heads, m.hd), m)
-    k = _rope(_mm(h, g("wk"), quant).reshape(B, T, m.kv, m.hd), m)
-    v = _mm(h, g("wv"), quant).reshape(B, T, m.kv, m.hd)
-    q = q.reshape(B, T, m.kv, m.heads // m.kv, m.hd)
-    s = jnp.einsum("bqkgh,bskh->bkgqs", q, k, precision=HI) / m.hd ** 0.5
+def attention(q, k, v, window: Optional[int]):
+    """Causal attention of q (B, T, heads, hd) over k, v (B, T, kv, hd),
+    each key/value head shared by heads // kv query heads, within
+    ``window`` positions where one is given; returns (B, T, heads * hd)."""
+    B, T, heads, hd = q.shape
+    kv = k.shape[2]
+    q = q.reshape(B, T, kv, heads // kv, hd)
+    s = jnp.einsum("bqkgh,bskh->bkgqs", q, k, precision=HI) / hd ** 0.5
     qi = np.arange(T)[:, None]
     ki = np.arange(T)[None, :]
     keep = ki <= qi
-    if m.window is not None:
-        keep = keep & (ki > qi - int(m.window))
+    if window is not None:
+        keep = keep & (ki > qi - int(window))
     s = jnp.where(jnp.asarray(keep), s, -jnp.inf)
-    a = jnp.einsum("bkgqs,bskh->bqkgh", jax.nn.softmax(s, -1), v,
-                   precision=HI).reshape(B, T, m.heads * m.hd)
-    x = x + _mm(a, g("wo"), quant)
-    h = _norm(x, g("ln2_w"), b("ln2_b"), m)
-    f = jax.nn.silu(_mm(h, g("w_gate"), quant)) * _mm(h, g("w_up"), quant)
-    return x + _mm(f, g("w_down"), quant)
+    return jnp.einsum("bkgqs,bskh->bqkgh", jax.nn.softmax(s, -1), v,
+                      precision=HI).reshape(B, T, heads * hd)
 
 
 @functools.lru_cache(maxsize=None)
-def _fns(c_json: str, quant: bool):
-    m = Dims(json.loads(c_json))
-    layer = jax.jit(lambda x, w, i: _layer(x, w, i, m, quant))
-    embed = jax.jit(lambda e, t: e[t].astype(jnp.float32))
+def _fns(family, c_json: str, quant: bool):
+    m = family.Dims(json.loads(c_json))
+    layer = jax.jit(lambda x, w, i: family.layer(x, w, i, m, quant))
+    embed = jax.jit(family.embed)
 
     def head(x, w, P, n_max):
         # positions P-1 .. P+n_max-2 of each row choose its served tokens
         idx = P[:, None] - 1 + jnp.arange(n_max)[None, :]
         idx = jnp.clip(idx, 0, x.shape[1] - 1)
         h = jnp.take_along_axis(x, idx[..., None], 1)
-        h = _norm(h, w["norm_f_w"], w.get("norm_f_b"), m)
-        out = w["embed"].T if m.tie else w["lm_head"]
-        return _mm(h, out, quant)
+        return family.head(h, w, m, quant)
 
     return layer, embed, jax.jit(head, static_argnums=3)
 
 
-def logits(c: dict, w, rows: np.ndarray, P: np.ndarray, n_max: int,
+def logits(family, c: dict, w, rows: np.ndarray, P: np.ndarray, n_max: int,
            quant: bool = False) -> jax.Array:
     """(B, n_max, V) float32 logits at positions P-1 .. P+n_max-2 of each
     row of ``rows`` (B, T) -- the positions that choose the tokens served
-    after a prompt of P tokens."""
-    m = Dims(c)
-    layer, embed, head = _fns(json.dumps(c, sort_keys=True), quant)
-    x = embed(w["embed"], jnp.asarray(rows))
-    for i in range(m.layers):
+    after a prompt of P tokens.  ``family``: the configuration ``c``'s
+    family module."""
+    layer, embed, head = _fns(family, json.dumps(c, sort_keys=True), quant)
+    x = embed(w, jnp.asarray(rows))
+    for i in range(family.Dims(c).layers):
         x = layer(x, w, jnp.int32(i))
     return head(x, w, jnp.asarray(P, jnp.int32), n_max)
 
@@ -141,15 +139,16 @@ def _gaps(ref, served, n, ctrl):
             jnp.where(live, best - alt, 0.0))
 
 
-def row_chunk(m: Dims, T: int, n_max: int, budget: float = 2e9) -> int:
+def row_chunk(m, T: int, n_max: int, budget: float = 2e9) -> int:
     """Rows per reference call that keep its largest temporaries (the
     attention scores of one layer, three sets of logits) under budget."""
     per_row = 4.0 * (m.heads * T * T + 3 * n_max * m.vocab + 8 * T * m.d)
     return max(1, int(budget // per_row))
 
 
-def served_gaps(c: dict, w, rows: List[np.ndarray], served: List[np.ndarray],
-                T: int, n_max: int, control: bool = False) -> Dict:
+def served_gaps(family, c: dict, w, rows: List[np.ndarray],
+                served: List[np.ndarray], T: int, n_max: int,
+                control: bool = False) -> Dict:
     """The reference's reading of served tokens.
 
     ``rows[i]`` is the token row the program prefilled for request i (its
@@ -157,8 +156,7 @@ def served_gaps(c: dict, w, rows: List[np.ndarray], served: List[np.ndarray],
     Returns the widest gap over every served token (``served_gap``) and,
     with ``control``, the widest gap of the tokens the float8 control puts
     first at the same positions (``control_gap``)."""
-    m = Dims(c)
-    step = row_chunk(m, T, n_max)
+    step = row_chunk(family.Dims(c), T, n_max)
     # pad to whole chunks (rows with nothing served) so that every call
     # has one shape and compiles once
     B = -(-len(rows) // step) * step
@@ -174,8 +172,8 @@ def served_gaps(c: dict, w, rows: List[np.ndarray], served: List[np.ndarray],
     served_gap, control_gap = [], []
     for lo in range(0, B, step):
         sl = slice(lo, lo + step)
-        ref = logits(c, w, toks[sl], P[sl], n_max)
-        ctl = logits(c, w, toks[sl], P[sl], n_max, quant=True) \
+        ref = logits(family, c, w, toks[sl], P[sl], n_max)
+        ctl = logits(family, c, w, toks[sl], P[sl], n_max, quant=True) \
             if control else ref
         g, a = _gaps(ref, jnp.asarray(S[sl]), jnp.asarray(n[sl]), ctl)
         served_gap.append(np.asarray(g))

@@ -1,13 +1,15 @@
 """One run of one cell: set-up, the measured window, the output check, the
 metrics, and the result line.
 
-    set-up   the configuration's weights (one jitted call on the device), the
+    set-up   the configuration's weights (one jitted call on the device;
+             with a mesh, straight into the program's shardings), the
              program's ``ServingEngine`` with the default ``SpecConfig``
-             (which builds the drafter's tables), a warm-up request per
-             prompt bucket served to the end (every program the window runs
-             is compiled or loaded from the cache), and for a closed loop
-             the clients' first requests admitted
-    window   ``--seconds`` of traffic through ``submit`` / ``step``
+             and that mesh (it builds the drafter's tables), a warm-up
+             request per prompt bucket served to the end (every program
+             the window runs is compiled or loaded from the cache), and
+             for a closed loop the clients' first requests admitted
+    window   ``--seconds`` of traffic through ``submit`` / ``step``; a
+             traced run records its last ``TRACE_S`` seconds
     after    the requests still open are served to the end (a minute at
              most), peak memory is read, the program's state is freed, and
              a sample of finished requests is checked against the plain
@@ -33,6 +35,10 @@ from . import traffic as tr
 
 # a request still open this long after the window closed never came
 DRAIN_S = 60.0
+# a traced run records the window's last seconds, not all of it: stopping
+# the profiler takes 25-45 us a device event on v5e hosts, which run 0.2-0.5
+# million events a second, and a run has to end within 360 s
+TRACE_S = 5.0
 BOS = 257          # the program's byte tokenizer: BOS, which also pads
 # a compilation, or a load from the persistent cache
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
@@ -44,7 +50,7 @@ class Run:
     """Everything a metric reader may read (``bench/metrics/*.py``)."""
     root: str
     cell: spec.Cell
-    dims: model.Dims
+    dims: object                   # the family's Dims of the configuration
     traffic: dict
     seed: int
     seconds: float
@@ -58,6 +64,7 @@ class Run:
     t1: float
     setup_s: float
     trace: Optional[dict] = None
+    trace_t0: Optional[float] = None   # the traced part's start
 
     def peak(self) -> dict:
         """The chip's published peaks; an unknown chip is an error."""
@@ -161,7 +168,7 @@ def check(run: Run, weights, unfinished: List[drv.Rec],
     sample = [longest] + random.Random(f"{run.seed}:reference").sample(
         rest, k)
     g = reference.served_gaps(
-        run.cell.config_data, weights,
+        run.cell.family, run.cell.config_data, weights,
         [prompt_row(r.req.prompt, p["buckets"]) for r in sample],
         [r.output_ids for r in sample],
         T=max(p["buckets"]) + cap, n_max=cap, control=control)
@@ -189,17 +196,19 @@ def execute(root: str, cell: spec.Cell, seed: int, seconds: float,
     from repro.core.spec_engine import SpecConfig
     from repro.serving import ServingEngine
 
-    c, p = cell.config_data, cell.traffic_data
-    cfg = model.program_config(c, cell.config)
+    fam, c, p = cell.family, cell.config_data, cell.traffic_data
+    dims = fam.Dims(c)
+    mesh = model.make_mesh(c.get("mesh"), devices)
     # the weights are the configuration's, the same in every run: with
     # random weights the acceptance of the drafts is a property of the
     # draw, and weights drawn per seed moved tokens_per_s by 30% between
     # seeds (PERF.md); the seed draws the traffic
-    weights = model.make_weights(c, int(c["weights_seed"]))
+    weights = model.make_weights(fam, dims, int(c["weights_seed"]), mesh)
     engine = ServingEngine(
-        model.program_params(c, weights), cfg, SpecConfig(),
+        fam.program_params(dims, weights), fam.program_config(c, cell.config),
+        SpecConfig(),
         max_batch=int(p["slots"]), buckets=tuple(p["buckets"]),
-        max_new_cap=int(p["max_new_cap"]), sampling=False)
+        max_new_cap=int(p["max_new_cap"]), sampling=False, mesh=mesh)
     reqs, warm = tr.workload(p, seed, seconds)
     d = drv.Driver(engine)
     drv.warm_up(d, warm)
@@ -216,20 +225,24 @@ def execute(root: str, cell: spec.Cell, seed: int, seconds: float,
     def on_open():
         _sync(engine)
         setup["s"] = t_age0()
-        if trace:
-            opts = jax.profiler.ProfileOptions()
-            opts.python_tracer_level = 0
-            # the benchmark's own spans; nothing of JAX's host internals
-            opts.host_tracer_level = 1
-            jax.profiler.start_trace(tdir, profiler_options=opts)
-            # made after the trace starts: a span made before is not recorded
-            setup["span"] = jax.profiler.TraceAnnotation(tracing.WINDOW_SPAN)
-            setup["span"].__enter__()
 
+    def on_trace():
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        # the benchmark's own spans; nothing of JAX's host internals
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(tdir, profiler_options=opts)
+        # made after the trace starts: a span made before is not recorded
+        setup["span"] = jax.profiler.TraceAnnotation(tracing.WINDOW_SPAN)
+        setup["span"].__enter__()
+        setup["trace_t0"] = time.perf_counter()
+
+    mark = (max(0.0, seconds - TRACE_S), on_trace) if trace else None
     if p["loop"] == "open":
-        t0, t1 = drv.run_open(d, reqs, seconds, on_open)
+        t0, t1 = drv.run_open(d, reqs, seconds, on_open, mark)
     else:
-        t0, t1 = drv.run_closed(d, reqs, int(p["clients"]), seconds, on_open)
+        t0, t1 = drv.run_closed(d, reqs, int(p["clients"]), seconds, on_open,
+                                mark)
     _sync(engine)
     jax.monitoring.unregister_event_duration_listener(on_compile)
     if trace:
@@ -244,11 +257,12 @@ def execute(root: str, cell: spec.Cell, seed: int, seconds: float,
     unfinished = d.unfinished()
     peak = max((dv.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for dv in devices)
-    run = Run(root=root, cell=cell, dims=model.Dims(c), traffic=p,
+    run = Run(root=root, cell=cell, dims=dims, traffic=p,
               seed=seed, seconds=seconds, spec_k=engine.spec.k,
               spec_w=engine.spec.w, chips=len(devices),
               device_kind=devices[0].device_kind, recs=d.recs,
-              step_times=d.step_times, t0=t0, t1=t1, setup_s=setup["s"])
+              step_times=d.step_times, t0=t0, t1=t1, setup_s=setup["s"],
+              trace_t0=setup.get("trace_t0"))
     # free the program's state before the reference runs
     d.engine = None
     del engine
@@ -261,9 +275,13 @@ def execute(root: str, cell: spec.Cell, seed: int, seconds: float,
         files = [os.path.join(a, f) for a, _, fs in os.walk(tdir)
                  for f in fs if f.endswith(".xplane.pb")]
         t_red = time.perf_counter()
-        run.trace = tracing.reduce_file(files[0])
+        run.trace = t = tracing.reduce_file(files[0])
         print(f"trace: {os.path.getsize(files[0])} bytes read and reduced "
-              f"in {time.perf_counter() - t_red:.1f} s", file=sys.stderr)
+              f"in {time.perf_counter() - t_red:.1f} s; kept "
+              f"{t['window_s']:.3f} s of the {t['span_s']:.3f} s window, "
+              f"{t['events']} device op events; each chip's last op ends "
+              f"{[round(x, 4) for x in t['last_op_s']]} s into it",
+              file=sys.stderr)
         shutil.rmtree(tdir, ignore_errors=True)
     metrics = spec.metrics_of(root, cell.per_layer if trace
                               else cell.end_to_end, run)

@@ -1,5 +1,6 @@
 """Device time of the ``admit_slot`` programs (one prompt prefill per
-admitted request) as a share of the device's busy time, in percent."""
+admitted request) as a share of the device's busy time, in percent (both
+summed over the chips)."""
 
 
 def read(run):
@@ -7,4 +8,4 @@ def read(run):
     m = t.get("modules", {}).get("admit_slot")
     if not m or not t.get("busy_s"):
         return None
-    return 100.0 * m["s"] / t["busy_s"]
+    return 100.0 * m["s"] / (t["busy_s"] * t["chips"])

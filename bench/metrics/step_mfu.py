@@ -1,8 +1,10 @@
 """Useful model operations of the window over the traced window's length
-times the chips' peak, in percent (``bench/roofline/step.py``): every
+times the chips' peak, in percent (``bench/roofline/step.py``, counted by
+the sizes the configuration's family gives, ``Dims.flops_dims``): every
 prompt prefilled in the window and a lower bound on the output tokens its
 verify calls committed (``harness/accounting.py``); rejected draft
-positions count nothing."""
+positions count nothing.  The window is the part of it that every chip's
+trace holds."""
 import importlib.util
 import os
 
@@ -20,10 +22,7 @@ def _step(root):
 def read(run):
     if not run.trace or run.trace["window_s"] <= 0:
         return None
-    st, m = _step(run.root), run.dims
-    dims = dict(non_embedding=m.non_embedding_params(), d_model=m.d,
-                vocab=m.vocab, layers=m.layers, heads=m.heads,
-                head_dim=m.hd, window=m.window)
+    st, dims = _step(run.root), run.dims.flops_dims()
     flops = 0.0
     for tokens, prompt, prefilled in committed_in_window(run):
         if prefilled:

@@ -19,10 +19,11 @@ def served(tmp_path_factory):
     runner.import_program(root)
     from repro.core.spec_engine import SpecConfig
     from repro.serving import ServingEngine
-    c, p = cell.config_data, cell.traffic_data
-    w = model.make_weights(c, 123)
-    eng = ServingEngine(model.program_params(c, w),
-                        model.program_config(c, "tiny"), SpecConfig(),
+    fam, c, p = cell.family, cell.config_data, cell.traffic_data
+    dims = fam.Dims(c)
+    w = model.make_weights(fam, dims, 123)
+    eng = ServingEngine(fam.program_params(dims, w),
+                        fam.program_config(c, cell.config), SpecConfig(),
                         max_batch=p["slots"], buckets=tuple(p["buckets"]),
                         max_new_cap=p["max_new_cap"], sampling=False)
     reqs, warm = traffic.workload(p, 123, 1.0)
@@ -32,12 +33,12 @@ def served(tmp_path_factory):
     d.drain(30.0)
     done = [r for r in d.recs if r.completed is not None][:8]
     rows = [runner.prompt_row(r.req.prompt, p["buckets"]) for r in done]
-    return c, w, rows, [r.output_ids for r in done], p
+    return (fam, c), w, rows, [r.output_ids for r in done], p
 
 
 def test_control_reads_far_above_the_program(served):
-    c, w, rows, outs, p = served
-    g = reference.served_gaps(c, w, rows, outs,
+    (fam, c), w, rows, outs, p = served
+    g = reference.served_gaps(fam, c, w, rows, outs,
                               T=max(p["buckets"]) + p["max_new_cap"],
                               n_max=p["max_new_cap"], control=True)
     assert g["served_tokens"] > 50

@@ -1,6 +1,7 @@
 """With the timed path broken underneath, a run's ``correct`` comes out
-false: once for each fault a served cell can have.  (The exchange between
-chips cannot be left out: every cell runs on one chip.)"""
+false: once for each fault a served cell can have.  (A cell on a mesh has
+these faults and one more, the exchange between chips left out:
+``test_bench_mesh.py`` plants all four on a four-device mesh.)"""
 import jax
 import numpy as np
 import pytest

@@ -12,7 +12,7 @@ import pytest
 
 import tiny
 from tiny import BENCH, ROOT
-from harness import runner, spec, tracing
+from harness import accounting, runner, spec, tracing
 
 
 @pytest.fixture(scope="module")
@@ -64,9 +64,10 @@ def test_open_loop_cell_runs_and_is_correct(root):
 
 
 def test_traced_run_reports_the_per_layer_metrics(root, monkeypatch):
-    fake = {"window_s": 2.0, "busy_s": 1.0, "chips": 1, "modules": {},
-            "kernels": {}, "top_ops": [["fusion", 0.5]],
-            "gaps": [["bench.step", 0.1]]}
+    fake = {"window_s": 2.0, "span_s": 2.1, "last_op_s": [2.0],
+            "events": 9, "busy_s": 1.0, "busy_by_chip": [1.0], "chips": 1,
+            "modules": {}, "kernels": {}, "ops_by_chip": [{"fusion": 0.5}],
+            "top_ops": [["fusion", 0.5]], "gaps": [["bench.step", 0.1]]}
     monkeypatch.setattr(tracing, "reduce_file", lambda path: fake)
     out = _execute(root, "tiny.tiny-closed", trace=True)
     assert out["correct"] is True
@@ -76,6 +77,25 @@ def test_traced_run_reports_the_per_layer_metrics(root, monkeypatch):
     assert out["breakdown"]["idle_gaps"] == [["bench.step", 0.1]]
 
 
+def test_a_traced_run_records_the_windows_last_seconds(root, monkeypatch):
+    fake = {"window_s": 0.5, "span_s": 0.5, "last_op_s": [0.5],
+            "events": 9, "busy_s": 0.4, "busy_by_chip": [0.4], "chips": 1,
+            "modules": {}, "kernels": {}, "ops_by_chip": [{"fusion": 0.4}],
+            "top_ops": [["fusion", 0.4]], "gaps": [["bench.step", 0.1]]}
+    monkeypatch.setattr(tracing, "reduce_file", lambda path: fake)
+    monkeypatch.setattr(runner, "TRACE_S", 0.5)
+    out, run = runner.execute(root, spec.load_cell(root, "tiny.tiny-closed"),
+                              5, 2.0, True, jax.devices()[:1], lambda: 1.0)
+    assert out["correct"] is True
+    # the trace starts at the first step due 1.5 s into the 2 s window
+    assert 1.5 <= run.trace_t0 - run.t0 < 1.9
+    lo, hi = accounting.window(run)
+    assert lo == run.trace_t0 and hi == min(run.t1, run.trace_t0 + 0.5)
+    assert accounting.window_steps(run)
+    assert all(run.trace_t0 < run.step_times[s]
+               for s in accounting.window_steps(run))
+
+
 def test_benchmark_file_names_every_reader_and_data_file():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
@@ -83,7 +103,8 @@ def test_benchmark_file_names_every_reader_and_data_file():
         assert spec.reader(ROOT, m["name"])
     for w in bench["workloads"]:
         cell = spec.load_cell(ROOT, w["name"])
-        assert cell.chips == 1
+        assert cell.chips == spec.mesh_size(cell.config_data)
+        assert cell.family.Dims(cell.config_data).layers > 0
         assert cell.limits["served_gap"] > 0
 
 

@@ -7,7 +7,7 @@ import os
 import pytest
 
 from tiny import BENCH, ROOT
-from harness import accounting, model, runner
+from harness import accounting, runner, spec
 from harness.driver import Rec
 from harness.traffic import Request
 
@@ -31,10 +31,11 @@ def _run(recs, step_times, t0, t1, trace=None, kind="TPU v5 lite"):
     with open(os.path.join(BENCH, "configs", "stablelm-2-1.6b.json")) as f:
         c = json.load(f)
     return runner.Run(
-        root=ROOT, cell=None, dims=model.Dims(c),
+        root=ROOT, cell=None, dims=spec.family(ROOT, c["family"]).Dims(c),
         traffic={"buckets": [256, 512, 1024]}, seed=0, seconds=t1 - t0,
         spec_k=10, spec_w=10, chips=1, device_kind=kind, recs=recs,
-        step_times=step_times, t0=t0, t1=t1, setup_s=1.0, trace=trace)
+        step_times=step_times, t0=t0, t1=t1, setup_s=1.0, trace=trace,
+        trace_t0=t0 if trace else None)
 
 
 def test_unknown_device_kind_is_an_error():
@@ -81,7 +82,7 @@ def test_mfu_counts_no_rejected_draft_positions():
     req = Request("code", "x" * 99, 100, 6)
     rec = Rec(req, 0, 0.0, admitted=1.0, admit_step=1, completed=3.5, new_tokens=6, calls=3)
     run = _run([rec], [0.5, 1.5, 2.5, 3.5, 4.5], 1.0, 3.6,
-               {"window_s": 1.0, "busy_s": 1.0, "chips": 1})
+               {"window_s": 2.6, "busy_s": 1.0, "chips": 1})
     (tokens, prompt, prefilled), = accounting.committed_in_window(run)
     assert (tokens, prompt, prefilled) == (5, 100, True)
     m = run.dims
@@ -91,7 +92,7 @@ def test_mfu_counts_no_rejected_draft_positions():
     want = step.prefill_flops(100, **dims) + 5 * step.token_flops(100,
                                                                   **dims)
     got = _load("metrics/step_mfu").read(run)
-    assert got == pytest.approx(100.0 * want / PEAK["flops"])
+    assert got == pytest.approx(100.0 * want / (2.6 * PEAK["flops"]))
     assert got < 100.0
 
 

@@ -109,6 +109,8 @@ def test_the_old_trace_reads_as_before(old):
     without spans or scopes."""
     _, base = old
     assert base["window_s"] == pytest.approx(0.039486358, rel=1e-12)
+    assert base["span_s"] == base["window_s"]
+    assert base["last_op_s"] == [pytest.approx(0.038886159, rel=1e-12)]
     assert base["busy_s"] == pytest.approx(0.038685239, rel=1e-12)
     assert base["modules"] == {"spec_step": {"s": pytest.approx(
         0.038686358, rel=1e-12), "n": 1}}

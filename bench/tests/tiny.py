@@ -8,11 +8,16 @@ ROOT = os.path.dirname(BENCH)
 
 CONFIG = {
     "source": "a tiny stand-in for the CPU tests", "model_type": "mistral",
+    "family": "dense",
     "hidden_act": "silu", "hidden_size": 64, "intermediate_size": 128,
     "num_attention_heads": 4, "num_key_value_heads": 2,
     "num_hidden_layers": 2, "vocab_size": 320, "rope_theta": 10000.0,
     "rms_norm_eps": 1e-5, "tie_word_embeddings": False,
     "torch_dtype": "float32", "weights_seed": 0}
+# the same widths split four ways on a (data 1, model 4) mesh: query and
+# key/value heads, the feed-forward width and the vocabulary all divide
+MESH_CONFIG = dict(CONFIG, num_attention_heads=8, num_key_value_heads=4,
+                   head_dim=8)
 CLOSED = {
     "loop": "closed", "slots": 2, "clients": 3,
     "mix": {"code": 1, "math": 1, "chat": 1},
