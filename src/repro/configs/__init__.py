@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned architectures + the paper's own.
+"""Architecture registry: the assigned architectures + the paper's own.
 
 ``get_config(arch)`` / ``get_smoke_config(arch)`` are the ``--arch <id>``
 entry points used by the launcher, dry-run and benchmarks.
@@ -11,7 +11,8 @@ import dataclasses
 from typing import Dict, List
 
 from ..models.config import ModelConfig
-from . import (deepseek_moe_16b, gemma_2b, glm4_9b, hubert_xlarge,
+from . import (deepseek_moe_16b, deepseek_v2_lite, gemma_2b, glm4_9b,
+               hubert_xlarge,
                jamba_1_5_large_398b, mistral_7b, mixtral_8x7b,
                nemotron_4_340b, qwen2_vl_72b, stablelm_1_6b, xlstm_125m)
 
@@ -26,6 +27,7 @@ _MODULES = {
     "nemotron-4-340b": nemotron_4_340b,
     "glm4-9b": glm4_9b,
     "deepseek-moe-16b": deepseek_moe_16b,
+    "deepseek-v2-lite": deepseek_v2_lite,
     "mistral-7b": mistral_7b,            # the paper's own model
 }
 
@@ -50,7 +52,8 @@ def long_context_variant(cfg: ModelConfig) -> ModelConfig:
     has_attn = any(b.mixer == "attn"
                    for b in (tuple(cfg.prefix_blocks)
                              + tuple(cfg.block_pattern)))
-    if not has_attn or cfg.sliding_window is not None:
+    if not has_attn or cfg.sliding_window is not None or cfg.mla:
+        # a latent (MLA) cache has no ring layout: it stays linear
         return cfg
     return dataclasses.replace(
         cfg, name=cfg.name + "+swa", sliding_window=LONG_CONTEXT_WINDOW)

@@ -242,7 +242,8 @@ def _draft(spec: SpecConfig, tables: NGramTables, buf, buf_len, last):
     return d, v, n_ctx.astype(jnp.int32)
 
 
-def _init_stats(spec: SpecConfig, B: int) -> Dict[str, jnp.ndarray]:
+def _init_stats(spec: SpecConfig, B: int,
+                cfg: ModelConfig) -> Dict[str, jnp.ndarray]:
     # tree mode ranks over root-to-leaf PATHS, not drafter rows
     ranks = (T.num_paths(spec.k, spec.w, spec.tree_branch) if spec.tree
              else max(spec.k, 1))
@@ -270,6 +271,12 @@ def _init_stats(spec: SpecConfig, B: int) -> Dict[str, jnp.ndarray]:
         # DecodeState and zeroed by the same slot-reset sweep as the
         # call/token counters (admission AND release)
         st.update(init_arm_stats(B, len(spec.arms)))
+    if M.has_experts(cfg):
+        # rows of the slot's verify calls routed to the experts this
+        # model holds: summed over calls, layers and experts, and the
+        # largest any one held expert of one layer took in one call
+        st["expert_rows"] = jnp.zeros((B,), jnp.int32)
+        st["expert_rows_max"] = jnp.zeros((B,), jnp.int32)
     return st
 
 
@@ -334,7 +341,7 @@ def empty_decode_state(cfg: ModelConfig, spec: SpecConfig, num_slots: int,
         done=jnp.ones((B,), bool),
         active=jnp.zeros((B,), bool),
         model=model,
-        stats=_init_stats(spec, B),
+        stats=_init_stats(spec, B, cfg),
         **_sampling_leaves(B))
 
 
@@ -429,7 +436,7 @@ def init_decode_state(params, cfg: ModelConfig, spec: SpecConfig,
     else:
         first = jnp.argmax(logits_p[:, -1], axis=-1).astype(jnp.int32)
     buf = buf.at[:, P].set(first)
-    stats = _init_stats(spec, B)
+    stats = _init_stats(spec, B, cfg)
     stats["tokens"] = stats["tokens"] + 1
     return DecodeState(
         buf=buf,
@@ -664,6 +671,7 @@ def _spec_body(params, cfg: ModelConfig, spec: SpecConfig,
                 [jnp.broadcast_to(last[:, None, None], (B, spec.k, 1)),
                  drafts], axis=-1)                              # (B,k,w+1)
             logits, tails = M.verify(params, cfg, state_c, rows)
+        tails, routed = M.split_expert_rows(tails)
     with jax.named_scope("spec.commit"):
         if spec.tree:
             if spec.sampling:
@@ -722,10 +730,10 @@ def _spec_body(params, cfg: ModelConfig, spec: SpecConfig,
             # paged paths unchanged
             sel = jnp.asarray(topo.path_inputs,
                               jnp.int32)[acc.winner]            # (B,w+1)
-            idx = sel[None, :, None, :, None, None]
-            tails = {g: {kk: jnp.take_along_axis(tt, idx, axis=3)
-                         for kk, tt in d.items()}
-                     for g, d in tails.items()}
+            tails = {g: {kk: jnp.take_along_axis(
+                tt, sel.reshape((1, B, 1, -1) + (1,) * (tt.ndim - 4)),
+                axis=3) for kk, tt in d.items()}
+                for g, d in tails.items()}
             state_n = M.commit_kv_tails(cfg, state_c, tails,
                                         jnp.zeros((B,), jnp.int32),
                                         n_commit)
@@ -776,6 +784,13 @@ def _spec_body(params, cfg: ModelConfig, spec: SpecConfig,
             # (bonus included — the same tokens-per-call quantity
             # AdaptiveKW tracks)
             st = update_arm_stats(st, arm, n_commit, active, spec.adapt_ema)
+        if M.has_experts(cfg):
+            # routed: (layers, B, held experts) rows of each slot's call
+            st["expert_rows"] = st["expert_rows"] + jnp.where(
+                active, routed.sum((0, 2)), 0)
+            st["expert_rows_max"] = jnp.maximum(
+                st["expert_rows_max"],
+                jnp.where(active, routed.max((0, 2)), 0))
         return dataclasses.replace(s, buf=buf_n, buf_len=len_n,
                                    done=done_n, model=state_n, stats=st,
                                    rng_key=carry_keys)

@@ -23,7 +23,7 @@ from typing import Iterator, Optional
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .sharding import kv_cache_pspec, resolve_axis
+from .sharding import cache_pspec, resolve_axis
 
 _MESH: Optional[Mesh] = None
 
@@ -70,14 +70,14 @@ def activated(mesh: Optional[Mesh]) -> Iterator[None]:
 
 
 def splits_cache_rows(shape) -> bool:
-    """Whether the active mesh splits a linear KV cache (R, B, S, KV, hd)
-    over its slot or sequence dim, by the state's own rule
-    (``sharding.kv_cache_pspec``).  Where it does, a write at a per-row
+    """Whether the active mesh splits a linear cache (R, B, S, KV, hd), or
+    a latent one (R, B, S, L), over its slot or sequence dim, by the
+    state's own rule (``sharding.cache_pspec``).  Where it does, a write at a per-row
     dynamic offset makes GSPMD all-gather the cache, so the speculative
     commit scatters instead (``model.commit_kv_tails``)."""
     if _MESH is None:
         return False
-    spec = tuple(kv_cache_pspec(_MESH, tuple(shape)))
+    spec = tuple(cache_pspec(_MESH, tuple(shape)))
     return any(_MESH.shape[a] > 1 for ax in spec[1:3] if ax is not None
                for a in ((ax,) if isinstance(ax, str) else ax))
 

@@ -141,6 +141,12 @@ _PARAM_RULES: Dict[str, Tuple[Optional[str], ...]] = {
     "wk": ("embed", "kv"),
     "wv": ("embed", "kv"),
     "wo": ("heads", "embed"),
+    # latent attention (MLA): the down-projection to the shared latent and
+    # its norm replicate over "model"; the per-head up-projection splits
+    # heads like wq
+    "wkv_a": ("embed", None),
+    "kv_norm": (None,),
+    "wkv_b": (None, "heads"),
     # dense mlps (and shared experts)
     "w_gate": ("embed", "ffn"),
     "w_up": ("embed", "ffn"),
@@ -263,6 +269,20 @@ def kv_cache_pspec(mesh: Mesh, shape: Tuple[int, ...]) -> P:
     return P(None, batch, seq_ax, kv_ax, None)
 
 
+def latent_cache_pspec(mesh: Mesh, shape: Tuple[int, ...]) -> P:
+    """PartitionSpec of an MLA latent cache leaf (R, B, S, L): the K/V
+    rule for one key/value head, which the latent is to every head."""
+    R, B, S, L = shape
+    spec = tuple(kv_cache_pspec(mesh, (R, B, S, 1, L)))
+    return P(*spec[:3], None)
+
+
+def cache_pspec(mesh: Mesh, shape: Tuple[int, ...]) -> P:
+    """PartitionSpec of a linear cache leaf: the K/V pair or the latent."""
+    return (latent_cache_pspec(mesh, shape) if len(shape) == 4
+            else kv_cache_pspec(mesh, shape))
+
+
 def state_pspec(mesh: Mesh, path, leaf) -> P:
     names = _path_names(path)
     name = names[-1]
@@ -273,6 +293,8 @@ def state_pspec(mesh: Mesh, path, leaf) -> P:
     batch = _batch_axes(mesh, B)
     if name in ("k", "v"):                      # (R, B, S, KV, hd)
         return kv_cache_pspec(mesh, shape)
+    if name == "latent":                        # (R, B, S, L)
+        return latent_cache_pspec(mesh, shape)
     if name == "conv":                          # (R, B, dc-1, di)
         return P(None, batch, None, resolve_axis(mesh, "ffn", shape[-1]))
     if name == "ssm":                           # (R, B, di, ds)
@@ -326,6 +348,8 @@ DECODE_STATE_LEAF_RULES: Dict[str, str] = {
     "k": "KV cache: kv-heads over 'model' else sequence fallback; "
          "paged pool: page axis over ('pod','data')[+'model']",
     "v": "same rule as 'k'",
+    "latent": "MLA latent cache: the K/V rule for one shared head "
+              "(sequence over 'model' where it divides)",
     "conv": "mamba conv window: channel dim over 'ffn'->'model'",
     "ssm": "mamba ssm state: inner dim over 'ffn'->'model'",
     "C": "mlstm covariance: heads over 'model' else head_dim",

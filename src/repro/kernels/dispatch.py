@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import ops, ref
+from .expert_gmm import expert_gmm_call
 
 BACKENDS = ("xla", "pallas", "auto")
 LANE = 128          # TPU lane width: last-dim tile of every VMEM block
@@ -113,11 +114,13 @@ def align_cache_len(n: int, block_s: int = 0) -> int:
 # ----------------------------------------------------------------------------
 def pallas_verify_supported(cfg) -> bool:
     """Kernel-eligibility for a ModelConfig: the Pallas verify kernel
-    implements the linear-cache, no-softcap contract; configs outside it
-    (Gemma softcap, Mixtral sliding-window ring cache) keep the XLA path
-    even under ``backend="pallas"``."""
+    implements the linear-cache, no-softcap, per-head K/V contract; configs
+    outside it (Gemma softcap, Mixtral sliding-window ring cache, the
+    latent cache of MLA) keep the XLA path even under
+    ``backend="pallas"``."""
     return (cfg.attn_logit_softcap is None
-            and cfg.sliding_window is None)
+            and cfg.sliding_window is None
+            and not cfg.mla)
 
 
 def _static_mask(tail_mask) -> Optional[Tuple[Tuple[bool, ...], ...]]:
@@ -208,3 +211,37 @@ def ngram_sweep(buf: jnp.ndarray, query: jnp.ndarray, cur_len: jnp.ndarray,
     fn = lambda b, qq, c: ref.ngram_match_ref(b, qq, c[None], w=w)
     return jax.vmap(fn)(bufp, query.astype(jnp.int32),
                         cur_len.astype(jnp.int32))
+
+
+# ----------------------------------------------------------------------------
+# grouped matmul over the held experts (dropless MoE, models/moe.py)
+# ----------------------------------------------------------------------------
+EXPERT_TILE = 128   # rows per tile of the grouped matmul: one MXU pass
+
+
+def expert_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray, layer,
+                  tile_group: jnp.ndarray, live: jnp.ndarray, *,
+                  backend: str, name: str = "expert_gmm") -> jnp.ndarray:
+    """lhs (M, K) rows in tiles of ``EXPERT_TILE``, tile i multiplied by
+    expert ``tile_group[i]`` of layer ``layer`` of rhs (R, E, K, N), for the
+    first ``live`` tiles (the layout of ``models/moe.expert_layout``).
+    Returns (M, N) in lhs's dtype, accumulated in float32; rows past the
+    live tiles are unspecified.
+
+    The Pallas kernel (``kernels/expert_gmm.py``, its custom call named
+    ``name``), which reads the layer's weights in place, where ``backend``
+    resolves to pallas and no activation sharder is installed (a
+    single-device kernel the SPMD partitioner cannot split); elsewhere
+    ``lax.ragged_dot`` over the same groups."""
+    from ..distributed import act_sharding
+    if use_pallas(backend) and not act_sharding.installed():
+        return expert_gmm_call(lhs, rhs, layer, tile_group, live,
+                               tm=EXPERT_TILE, name=name,
+                               interpret=default_interpret())
+    E = rhs.shape[1]
+    in_live = jnp.arange(tile_group.shape[0]) < live
+    sizes = jnp.zeros((E,), jnp.int32).at[tile_group].add(
+        jnp.where(in_live, EXPERT_TILE, 0))
+    return jax.lax.ragged_dot(lhs, rhs[layer].astype(lhs.dtype), sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(lhs.dtype)

@@ -86,7 +86,8 @@ BLOCKWISE_BLOCK = 1024
 
 
 def _blockwise_attention(q, k, v, q_pos, k_pos, cfg, causal: bool,
-                         block: int = BLOCKWISE_BLOCK) -> jnp.ndarray:
+                         block: int = BLOCKWISE_BLOCK,
+                         scale: Optional[float] = None) -> jnp.ndarray:
     """Flash-style attention: scan over key blocks with online softmax.
 
     Same contract as ``masked_attention``; numerically identical softmax.
@@ -97,7 +98,8 @@ def _blockwise_attention(q, k, v, q_pos, k_pos, cfg, causal: bool,
     nb = S // block
     assert S % block == 0
     qf = q.reshape(B, T, KV, G, hd).astype(jnp.float32)
-    scale = 1.0 / (hd ** 0.5)
+    if scale is None:
+        scale = 1.0 / (hd ** 0.5)
 
     def rs(a):  # (B,S,...) -> (nb, B, bs, ...)
         return jnp.moveaxis(a.reshape(B, nb, block, *a.shape[2:]), 1, 0)
@@ -146,21 +148,25 @@ def _blockwise_attention(q, k, v, q_pos, k_pos, cfg, causal: bool,
 
 def masked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      q_pos: jnp.ndarray, k_pos: jnp.ndarray,
-                     cfg: ModelConfig, causal: bool) -> jnp.ndarray:
+                     cfg: ModelConfig, causal: bool,
+                     scale: Optional[float] = None) -> jnp.ndarray:
     """q: (B,T,H,hd) k/v: (B,S,KV,hd); *_pos: (B,T)/(B,S) (-1 = invalid key).
 
-    Returns (B, T, H, hd).  GQA via reshape to (KV, G) groups.
+    Returns (B, T, H, hd).  GQA via reshape to (KV, G) groups.  ``scale``
+    multiplies the logits (default hd ** -0.5).
     Dispatches to the blockwise path for large key counts.
     """
     S = k.shape[1]
     if S >= BLOCKWISE_THRESHOLD and S % BLOCKWISE_BLOCK == 0:
-        return _blockwise_attention(q, k, v, q_pos, k_pos, cfg, causal)
+        return _blockwise_attention(q, k, v, q_pos, k_pos, cfg, causal,
+                                    scale=scale)
     B, T, H, hd = q.shape
     S, KV = k.shape[1], k.shape[2]
     G = H // KV
     qf = q.reshape(B, T, KV, G, hd).astype(jnp.float32)
     kf = k.astype(jnp.float32)
-    logits = jnp.einsum("btkgh,bskh->bkgts", qf, kf) / (hd ** 0.5)
+    logits = jnp.einsum("btkgh,bskh->bkgts", qf, kf)
+    logits = logits / (hd ** 0.5) if scale is None else logits * scale
     if cfg.attn_logit_softcap:
         c = cfg.attn_logit_softcap
         logits = c * jnp.tanh(logits / c)
@@ -216,7 +222,8 @@ def attn_full(params: Params, x: jnp.ndarray, cfg: ModelConfig,
 
 def _verify_attention_xla(q, k_cache, v_cache, k_tail, v_tail, cache_pos,
                           pos2d, cfg: ModelConfig,
-                          tail_mask=None) -> jnp.ndarray:
+                          tail_mask=None,
+                          scale: Optional[float] = None) -> jnp.ndarray:
     """XLA backend of the bifurcated verify attention.
 
     q: (B,K,W1,H,hd); caches (B,S,KV,hd); tails (B,K,W1,KV,hd);
@@ -224,6 +231,7 @@ def _verify_attention_xla(q, k_cache, v_cache, k_tail, v_tail, cache_pos,
     pos2d: (B,W1) query positions.  ``tail_mask``: optional STATIC
     (W1, W1) bool tail visibility replacing the causal triangle — tree
     verification's ancestor mask (DESIGN.md §11; K == 1 there).
+    ``scale`` multiplies the logits (default hd ** -0.5).
     Returns (B,K,W1,H,hd) f32.
 
     This is the fully-general path (softcap, sliding-window ring caches,
@@ -238,7 +246,8 @@ def _verify_attention_xla(q, k_cache, v_cache, k_tail, v_tail, cache_pos,
     vn = v_tail.astype(jnp.float32)
     kc = k_cache.astype(jnp.float32)
     vc = v_cache.astype(jnp.float32)
-    scale = 1.0 / (hd ** 0.5)
+    if scale is None:
+        scale = 1.0 / (hd ** 0.5)
     # context logits: shared cache read once per sequence
     lc = jnp.einsum("bkwnGh,bsnh->bknGws", qg, kc) * scale
     from ..distributed import act_sharding

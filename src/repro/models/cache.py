@@ -19,6 +19,13 @@ the transformer can ``lax.scan`` over it):
                                leading dim R (R=1 for prefix groups).
   }
 
+Multi-head latent attention (``cfg.mla``, models/mla.py) keeps a third
+kind of decode state in its attention groups: one latent buffer
+``{"latent": (R, B, S, kv_lora_rank + qk_rope_head_dim)}`` per layer,
+shared by every head, in place of the K/V pair.  It is linear only: the
+paged layout refuses it (``paged_supported``), as it refuses windows.  The
+writes below take a tuple of caches, so the pair and the latent share them.
+
 Paged layout (DESIGN.md §8): instead of a per-slot linear buffer
 (R, B, S, KV, hd), attention groups hold ONE shared page pool
 (R, num_pages, page_size, KV, hd) and the state grows four extra leaves:
@@ -79,10 +86,24 @@ def group_ids(cfg: ModelConfig):
     return out
 
 
+def cache_names(group: Dict) -> Tuple[str, ...]:
+    """The cache leaves of an attention group: the K/V pair or the
+    latent."""
+    return ("latent",) if "latent" in group else ("k", "v")
+
+
+def buffer_len(group: Dict) -> int:
+    """Positions per slot of an attention group's linear cache."""
+    return group[cache_names(group)[0]].shape[2]
+
+
 def _init_group(cfg: ModelConfig, spec: BlockSpec, R: int, batch: int,
                 S: int) -> Dict:
     """Empty decode-state group for one layer position (linear ATTN layout)."""
     hd = cfg.resolved_head_dim
+    if spec.mixer == ATTN and cfg.mla:
+        return {"latent": jnp.zeros((R, batch, S, cfg.latent_dim),
+                                    cfg.compute_dtype)}
     if spec.mixer == ATTN:
         shape = (R, batch, S, cfg.num_kv_heads, hd)
         return {"k": jnp.zeros(shape, cfg.compute_dtype),
@@ -185,7 +206,7 @@ def reset_slot(cfg: ModelConfig, state: Dict, slot) -> Dict:
     S = 1
     for gid, spec, _ in group_ids(cfg):
         if spec.mixer == ATTN:
-            S = state["groups"][gid]["k"].shape[2]
+            S = buffer_len(state["groups"][gid])
             break
     return insert_slot(state, init_state(cfg, 1, S), slot)
 
@@ -206,8 +227,9 @@ def default_page_size(cfg: ModelConfig) -> int:
 def paged_supported(cfg: ModelConfig) -> bool:
     """Paged layout implements linear-cache semantics only: sliding-window
     ring caches keep the per-slot ring buffer, and at least one attention
-    group must exist for paging to mean anything."""
-    return (cfg.sliding_window is None
+    group must exist for paging to mean anything.  The pool holds K/V
+    pairs: a latent (MLA) cache stays linear."""
+    return (cfg.sliding_window is None and not cfg.mla
             and any(spec.mixer == ATTN for _, spec, _ in group_ids(cfg)))
 
 
@@ -228,7 +250,7 @@ def init_paged_state(cfg: ModelConfig, batch: int, num_pages: int,
     stack, and every slot's page table is empty."""
     assert paged_supported(cfg), (
         f"{cfg.name}: paged KV requires a linear-cache attention arch "
-        f"(sliding_window=None, >=1 attn layer)")
+        f"(sliding_window=None, no latent cache, >=1 attn layer)")
     hd = cfg.resolved_head_dim
     groups = {}
     for gid, spec, R in group_ids(cfg):
@@ -471,13 +493,12 @@ def write_slots(cfg: ModelConfig, S: int, cur_len: jnp.ndarray,
     return pos.astype(jnp.int32)
 
 
-def kv_write(k_cache: jnp.ndarray, v_cache: jnp.ndarray,
-             k_new: jnp.ndarray, v_new: jnp.ndarray,
+def kv_write(caches: Tuple[jnp.ndarray, ...], news: Tuple[jnp.ndarray, ...],
              slots: jnp.ndarray,
-             gate: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray,
-                                                          jnp.ndarray]:
-    """Write new KV into slots. caches: (B,S,KV,hd); new: (B,T,KV,hd);
-    slots: (B,T). ``gate``: (B,T) bool — write only where True (spec commit).
+             gate: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, ...]:
+    """Write new positions into slots of each cache.  caches: (B, S, ...)
+    (the K/V pair, or the one latent); news: (B, T, ...) alike; slots:
+    (B, T).  ``gate``: (B, T) bool -- write only where True (spec commit).
 
     T == 1 (the production serve step) uses a one-hot masked select instead
     of a scatter: elementwise ops partition cleanly when the cache sequence
@@ -488,38 +509,35 @@ def kv_write(k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     (linear caches commit through ``kv_commit_in_place``).
     """
     B, T = slots.shape
-    S = k_cache.shape[1]
+    S = caches[0].shape[1]
+    trail = lambda a, c: a.reshape(a.shape + (1,) * (c.ndim - a.ndim))
     if T == 1:
         hit = (jnp.arange(S)[None, :] == slots)            # (B, S)
         if gate is not None:
             hit = hit & gate
-        m = hit[..., None, None]
-        k_cache = jnp.where(m, k_new.astype(k_cache.dtype), k_cache)
-        v_cache = jnp.where(m, v_new.astype(v_cache.dtype), v_cache)
-        return k_cache, v_cache
+        return tuple(jnp.where(trail(hit, c), n.astype(c.dtype), c)
+                     for c, n in zip(caches, news))
     b_idx = jnp.broadcast_to(jnp.arange(B)[:, None], (B, T))
-    if gate is not None:
-        old_k = k_cache[b_idx, slots]
-        old_v = v_cache[b_idx, slots]
-        k_new = jnp.where(gate[..., None, None], k_new.astype(k_cache.dtype),
-                          old_k)
-        v_new = jnp.where(gate[..., None, None], v_new.astype(v_cache.dtype),
-                          old_v)
-    k_cache = k_cache.at[b_idx, slots].set(k_new.astype(k_cache.dtype))
-    v_cache = v_cache.at[b_idx, slots].set(v_new.astype(v_cache.dtype))
-    return k_cache, v_cache
+    out = []
+    for c, n in zip(caches, news):
+        n = n.astype(c.dtype)
+        if gate is not None:
+            n = jnp.where(trail(gate, c), n, c[b_idx, slots])
+        out.append(c.at[b_idx, slots].set(n))
+    return tuple(out)
 
 
 COMMIT_TILE = 128    # positions: the TPU's lane tile
 
 
-def kv_commit_in_place(k_cache: jnp.ndarray, v_cache: jnp.ndarray,
-                       k_new: jnp.ndarray, v_new: jnp.ndarray,
+def kv_commit_in_place(caches: Tuple[jnp.ndarray, ...],
+                       news: Tuple[jnp.ndarray, ...],
                        cur_len: jnp.ndarray, n_commit: jnp.ndarray
-                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                       ) -> Tuple[jnp.ndarray, ...]:
     """Speculative commit into LINEAR caches, in the caches' own layout.
-    caches: (R, B, S, KV, hd) stacked over layers; new: (R, B, W1, KV, hd)
-    with W1 <= S; cur_len, n_commit: (B,).  Writes positions cur_len[b] ..
+    caches: (R, B, S, ...) stacked over layers (the K/V pair (R, B, S, KV,
+    hd), or the latent (R, B, S, L)); news: (R, B, W1, ...) alike, with
+    W1 <= S; cur_len, n_commit: (B,).  Writes positions cur_len[b] ..
     cur_len[b] + n_commit[b] - 1 of row b, drops those >= S, and leaves
     every other position bit-identical: the gated scatter's semantics.
 
@@ -543,58 +561,64 @@ def kv_commit_in_place(k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     start is clamped into the buffer, and the tail and gate shift by where
     the row's positions fall in the window.
     """
-    R, B, S, KV, hd = k_cache.shape
-    W1 = k_new.shape[2]
+    R, B, S = caches[0].shape[:3]
+    rest = caches[0].shape[3:]          # (KV, hd), or (L,) for the latent
+    W1 = news[0].shape[2]
     align = math.gcd(S, COMMIT_TILE)
     Wn = min(S, -(-(W1 + align - 1) // align) * align)
-    win = (R, 1, Wn, KV, hd)
+    win = (R, 1, Wn) + rest
+    zero = (0,) * len(rest)
     # the tail sits at window position `shift`: slicing it out of a
     # zero-padded copy (a gather would pull the cache into its layout)
-    pad = ((0, 0), (0, 0), (Wn, Wn - W1), (0, 0), (0, 0))
+    pad = ((0, 0), (0, 0), (Wn, Wn - W1)) + ((0, 0),) * len(rest)
 
     def write_row(b, caches):
         start = jnp.clip(cur_len[b], 0, S - Wn) & -align
         shift = jnp.minimum(cur_len[b] - start, Wn)
         src = jnp.arange(Wn) - shift
-        g = ((src >= 0) & (src < n_commit[b]))[None, None, :, None, None]
-        at = (0, b, start, 0, 0)
+        g = ((src >= 0) & (src < n_commit[b]))[
+            (None, None, slice(None)) + (None,) * len(rest)]
+        at = (0, b, start) + zero
         out = []
-        for c, new in zip(caches, (k_new, v_new)):
+        for c, new in zip(caches, news):
             t = jnp.pad(jax.lax.dynamic_slice_in_dim(new, b, 1, axis=1),
                         pad).astype(c.dtype)
-            t = jax.lax.dynamic_slice(t, (0, 0, Wn - shift, 0, 0), win)
+            t = jax.lax.dynamic_slice(t, (0, 0, Wn - shift) + zero, win)
             old = jax.lax.dynamic_slice(c, at, win,
                                         allow_negative_indices=False)
             out.append(jax.lax.dynamic_update_slice(
                 c, jnp.where(g, t, old), at, allow_negative_indices=False))
         return tuple(out)
 
-    return jax.lax.fori_loop(0, B, write_row, (k_cache, v_cache))
+    return jax.lax.fori_loop(0, B, write_row, tuple(caches))
 
 
-def prefill_write(cfg: ModelConfig, k_cache, v_cache, k_new, v_new,
-                  seq_mask: Optional[jnp.ndarray] = None):
-    """Write a full prefill block (positions 0..T-1) into an empty cache.
+def prefill_write(cfg: ModelConfig, caches: Tuple[jnp.ndarray, ...],
+                  news: Tuple[jnp.ndarray, ...],
+                  seq_mask: Optional[jnp.ndarray] = None
+                  ) -> Tuple[jnp.ndarray, ...]:
+    """Write a full prefill block (positions 0..T-1) into empty caches
+    (the K/V pair, or the latent).
 
     With a ring cache only the last S positions land (earlier ones are
     overwritten by the mod-S scatter, in order, which is exactly ring
     semantics).
     """
-    B, T = k_new.shape[:2]
-    S = k_cache.shape[1]
+    B, T = news[0].shape[:2]
+    S = caches[0].shape[1]
     if T > S:
         # ring cache shorter than the prompt: only the last S positions land
         # (slice explicitly — a mod-S scatter with duplicate slots would have
         # undefined winner order).
-        k_new, v_new = k_new[:, -S:], v_new[:, -S:]
+        news = tuple(n[:, -S:] for n in news)
         if seq_mask is not None:
             seq_mask = seq_mask[:, -S:]
         off = jnp.full((B,), T - S, jnp.int32)
         slots = write_slots(cfg, S, off, S)
-        return kv_write(k_cache, v_cache, k_new, v_new, slots, gate=seq_mask)
+        return kv_write(caches, news, slots, gate=seq_mask)
     cur0 = jnp.zeros((B,), jnp.int32)
     slots = write_slots(cfg, S, cur0, T)
-    return kv_write(k_cache, v_cache, k_new, v_new, slots, gate=seq_mask)
+    return kv_write(caches, news, slots, gate=seq_mask)
 
 
 # ----------------------------------------------------------------------------

@@ -40,6 +40,23 @@ class BlockSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rotary scaling (arXiv:2309.00071; DeepSeek-V2's ``rope_scaling``
+    of type "yarn"): the inverse frequencies blend the plain and the
+    ``factor``-interpolated ones between the correction dims that
+    ``beta_fast`` and ``beta_slow`` rotations give over
+    ``original_max_position`` positions, and the softmax scale grows by
+    ``mscale(factor, mscale_all_dim)`` squared where ``mscale`` equals
+    ``mscale_all_dim`` (the cos/sin factor is then 1)."""
+    factor: float = 1.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str = "model"
     arch_type: str = "dense"  # dense | moe | ssm | hybrid | vlm | audio
@@ -70,6 +87,18 @@ class ModelConfig:
     partial_rotary_factor: float = 1.0   # StableLM-2: 0.25, Nemotron: 0.5
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)  # t/h/w rotary halves
 
+    # YaRN scaling of the rotary frequencies (None: plain RoPE)
+    yarn: Optional[YaRN] = None
+
+    # Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434), on when
+    # kv_lora_rank > 0: every attention layer caches one latent of
+    # kv_lora_rank + qk_rope_head_dim values a position, shared by all
+    # heads (models/mla.py), instead of per-head K/V
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
     # Attention
     causal: bool = True
     sliding_window: Optional[int] = None  # Mixtral: 4096
@@ -90,8 +119,19 @@ class ModelConfig:
     num_shared_experts: int = 0   # DeepSeek-MoE: 2
     moe_d_ff: int = 0             # expert width (DeepSeek fine-grained: 1408)
     router_aux_loss_coef: float = 0.01
-    moe_impl: str = "scatter"     # scatter | dense (dense = oracle for tests)
+    # scatter | dense (dense = oracle for tests) | gmm (dropless grouped
+    # matmul over the experts held, models/moe.py)
+    moe_impl: str = "scatter"
     capacity_factor: float = 2.0
+    # top-k weights renormalised to sum to 1 (Mixtral, Jamba); DeepSeek-V2
+    # keeps the softmax weights as they are
+    norm_topk_prob: bool = True
+    # The experts this layer holds: ``experts_held`` of the router's
+    # ``num_experts``, from ``experts_offset`` on (0: all of them).  The
+    # router keeps its width; the layer computes only its own experts'
+    # part (one chip's share under expert parallelism).
+    experts_held: int = 0
+    experts_offset: int = 0
 
     # Mamba (Jamba)
     mamba_d_state: int = 16
@@ -147,6 +187,20 @@ class ModelConfig:
         return self.moe_d_ff if self.moe_d_ff else self.d_ff
 
     @property
+    def held_experts(self) -> int:
+        return self.experts_held if self.experts_held else self.num_experts
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Values an MLA cache holds per position: the latent and the
+        shared rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
     def mamba_d_inner(self) -> int:
         return self.mamba_expand * self.d_model
 
@@ -163,6 +217,20 @@ class ModelConfig:
             assert b.mlp in (SWIGLU, GEGLU, RELU2, GELU, MOE, NO_MLP), b
             if b.mlp == MOE:
                 assert self.num_experts > 0, self.name
+        assert self.moe_impl in ("scatter", "dense", "gmm"), self.moe_impl
+        assert 0 <= self.experts_offset and (
+            self.experts_offset + self.held_experts <= self.num_experts), (
+            self.name, self.experts_offset, self.held_experts)
+        if self.held_experts != self.num_experts:
+            # the capacity and oracle paths compute every expert
+            assert self.moe_impl == "gmm", (self.name, self.moe_impl)
+        if self.mla:
+            assert self.qk_rope_head_dim > 0 and self.qk_rope_head_dim % 2 \
+                == 0 and self.qk_nope_head_dim > 0 and self.v_head_dim > 0, \
+                self.name
+            assert self.rope == ROPE, (self.name, self.rope)
+            assert self.sliding_window is None and not self.qk_norm, \
+                self.name
         if self.encoder_only:
             assert not self.causal
         return self
@@ -178,7 +246,14 @@ class ModelConfig:
         blocks = list(self.prefix_blocks)
         blocks += list(self.block_pattern) * self.num_periods
         for b in blocks:
-            if b.mixer == ATTN:
+            if b.mixer == ATTN and self.mla:
+                H, r = self.num_heads, self.kv_lora_rank
+                total += d * H * (self.qk_nope_head_dim
+                                  + self.qk_rope_head_dim)          # q
+                total += d * self.latent_dim + r                  # kv_a
+                total += r * H * (self.qk_nope_head_dim + self.v_head_dim)
+                total += H * self.v_head_dim * d                  # o
+            elif b.mixer == ATTN:
                 total += d * (self.num_heads * hd) * 2  # q, o
                 total += d * (self.num_kv_heads * hd) * 2  # k, v
             elif b.mixer == MAMBA:
@@ -200,7 +275,7 @@ class ModelConfig:
                 total += 2 * d * self.d_ff
             elif b.mlp == MOE:
                 e_ff = self.expert_d_ff
-                eff_experts = self.num_experts + self.num_shared_experts
+                eff_experts = self.held_experts + self.num_shared_experts
                 if active_only:
                     eff_experts = self.num_experts_per_tok + self.num_shared_experts
                 total += eff_experts * 3 * d * e_ff

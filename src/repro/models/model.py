@@ -11,10 +11,10 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .cache import (group_ids, init_state, is_paged, key_positions,
-                    kv_commit_in_place, kv_write, paged_dims, paged_kv_write,
-                    phys_slots, write_slots)
-from .config import ATTN, MROPE, ModelConfig, layer_blocks
+from .cache import (buffer_len, cache_names, group_ids, init_state,
+                    is_paged, key_positions, kv_commit_in_place, kv_write,
+                    paged_dims, paged_kv_write, phys_slots, write_slots)
+from .config import ATTN, MOE, MROPE, ModelConfig, layer_blocks
 from .layers import apply_norm, embed_tokens, lm_logits
 from .transformer import init_params, run_stack
 
@@ -22,11 +22,27 @@ Params = Dict[str, Any]
 State = Dict[str, Any]
 
 __all__ = ["init_params", "init_state", "forward", "prefill", "decode",
-           "verify", "commit_kv_tails", "has_recurrent", "make_positions"]
+           "verify", "commit_kv_tails", "has_recurrent", "has_experts",
+           "split_expert_rows", "make_positions"]
 
 
 def has_recurrent(cfg: ModelConfig) -> bool:
     return any(b.mixer != ATTN for b in layer_blocks(cfg))
+
+
+def has_experts(cfg: ModelConfig) -> bool:
+    return any(b.mlp == MOE for b in layer_blocks(cfg))
+
+
+def split_expert_rows(tails: Dict) -> Tuple[Dict, Optional[jnp.ndarray]]:
+    """Separate ``verify``'s per-group ``expert_rows`` ((R, B, held) each)
+    from the cache tails.  Returns (tails without them, (layers, B, held)
+    rows routed to each held expert by each slot, or None)."""
+    rows = [g["expert_rows"] for g in tails.values() if "expert_rows" in g]
+    rest = {gid: {k: v for k, v in g.items() if k != "expert_rows"}
+            for gid, g in tails.items()}
+    rest = {gid: g for gid, g in rest.items() if g}
+    return rest, (jnp.concatenate(rows, 0) if rows else None)
 
 
 def make_positions(cfg: ModelConfig, B: int, T: int,
@@ -131,7 +147,7 @@ def decode(params: Params, cfg: ModelConfig, state: State,
             ctx["slots"] = phys_slots(state["page_table"],
                                       write_slots(cfg, S, cur, T), ps, NP)
         else:
-            S = state["groups"][gid0]["k"].shape[2]
+            S = buffer_len(state["groups"][gid0])
             ctx["slots"] = write_slots(cfg, S, cur, T)
         ctx["cache_pos"] = key_positions(cfg, S, cur)   # pre-write owners
         ctx["cur_len"] = cur        # scalar-prefetch operand (Pallas backend)
@@ -159,6 +175,10 @@ def verify(params: Params, cfg: ModelConfig, state: State,
     tokens: (B, k, w+1) — row i is [last_token, draft_i(0..w-1)].
     Returns (logits (B, k, w+1, V) f32, kv_tails for attention groups).
     State is NOT advanced (pure read).
+
+    Groups with an expert layer also carry ``expert_rows`` (R, B, held
+    experts): rows of each slot's call routed to each held expert
+    (``split_expert_rows``).
 
     Tree mode (DESIGN.md §11) passes the whole token tree as the single row
     k == 1 with two STATIC topology constants:
@@ -191,7 +211,7 @@ def verify(params: Params, cfg: ModelConfig, state: State,
             ctx["paged"] = True
             ctx["page_table"] = state["page_table"]
         else:
-            S = state["groups"][gid0]["k"].shape[2]
+            S = buffer_len(state["groups"][gid0])
         ctx["cache_pos"] = key_positions(cfg, S, cur)
         ctx["cur_len"] = cur        # scalar-prefetch operand (Pallas backend)
     x = _embed(params, cfg, tokens.reshape(B * K, W1), None)
@@ -204,7 +224,9 @@ def verify(params: Params, cfg: ModelConfig, state: State,
 def commit_kv_tails(cfg: ModelConfig, state: State, kv_tails: Dict,
                     winner: jnp.ndarray, n_commit: jnp.ndarray) -> State:
     """Fast commit for attention-only archs: write the winning row's accepted
-    KV tail into the shared cache (no replay forward needed).
+    KV tail (or latent tail, MLA) into the shared cache (no replay forward
+    needed).  ``kv_tails``: {gid: {"<cache>_tail": (R, B, K, W1, ...)}}
+    for each cache leaf of the group; other entries are ignored.
 
     Linear caches write it in place (``kv_commit_in_place``).  The gated
     scatter stays where that cannot work: ring caches (a tail may wrap past
@@ -221,35 +243,33 @@ def commit_kv_tails(cfg: ModelConfig, state: State, kv_tails: Dict,
         S = pps * ps
     else:
         gid0 = next(gid for gid, s, _ in group_ids(cfg) if s.mixer == ATTN)
-        S = state["groups"][gid0]["k"].shape[2]
+        S = buffer_len(state["groups"][gid0])
     ring = cfg.sliding_window is not None and cfg.sliding_window <= S
     for gid, tails in kv_tails.items():
-        k_t, v_t = tails["k_tail"], tails["v_tail"]  # (R,B,K,W1,KV,hd)
-        R, B, K, W1 = k_t.shape[:4]
-        wsel = winner.reshape(1, B, 1, 1, 1, 1)
-        k_w = jnp.take_along_axis(k_t, wsel, axis=2)[:, :, 0]  # (R,B,W1,KV,hd)
-        v_w = jnp.take_along_axis(v_t, wsel, axis=2)[:, :, 0]
-        kc, vc = state["groups"][gid]["k"], state["groups"][gid]["v"]
+        names = cache_names(state["groups"][gid])
+        tl = [tails[n + "_tail"] for n in names]      # (R, B, K, W1, ...)
+        R, B, K, W1 = tl[0].shape[:4]
+        wsel = winner.reshape((1, B, 1, 1) + (1,) * (tl[0].ndim - 4))
+        news = [jnp.take_along_axis(t, wsel, axis=2)[:, :, 0] for t in tl]
+        caches = tuple(state["groups"][gid][n] for n in names)
         if not (paged or ring or W1 > S
-                or act_sharding.splits_cache_rows(kc.shape)):
-            kc, vc = kv_commit_in_place(kc, vc, k_w, v_w, cur, n_commit)
-            groups[gid] = {"k": kc, "v": vc}
-            continue
-        slots = write_slots(cfg, S, cur, W1)
-        gate = jnp.arange(W1)[None, :] < n_commit[:, None]
-        if paged:
+                or act_sharding.splits_cache_rows(caches[0].shape)):
+            caches = kv_commit_in_place(caches, news, cur, n_commit)
+        elif paged:
+            slots = write_slots(cfg, S, cur, W1)
+            gate = jnp.arange(W1)[None, :] < n_commit[:, None]
             phys = phys_slots(state["page_table"], slots, ps, NP)
-            kc, vc = jax.vmap(
+            caches = jax.vmap(
                 lambda kp, vp, kn, vn: paged_kv_write(kp, vp, kn, vn, phys,
                                                       gate=gate)
-            )(state["groups"][gid]["k"], state["groups"][gid]["v"], k_w, v_w)
+            )(*caches, *news)
         else:
-            kc, vc = jax.vmap(
-                lambda kcache, vcache, kn, vn: kv_write(kcache, vcache,
-                                                        kn, vn, slots,
-                                                        gate=gate)
-            )(state["groups"][gid]["k"], state["groups"][gid]["v"], k_w, v_w)
-        groups[gid] = {"k": kc, "v": vc}
+            slots = write_slots(cfg, S, cur, W1)
+            gate = jnp.arange(W1)[None, :] < n_commit[:, None]
+            caches = jax.vmap(
+                lambda cs, ns: kv_write(cs, ns, slots, gate=gate)
+            )(caches, tuple(news))
+        groups[gid] = dict(zip(names, caches))
     return {**state, "cur_len": cur + n_commit, "groups": groups}
 
 

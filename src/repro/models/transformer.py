@@ -24,6 +24,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from . import mla as mla_lib
 from . import moe as moe_lib
 from .attention import attn_full, attn_verify, init_attention
 from .cache import (cache_buffer_len, group_ids, key_positions, kv_write,
@@ -44,7 +45,9 @@ Params = Dict[str, Any]
 def init_block(rng, cfg: ModelConfig, spec: BlockSpec) -> Params:
     ks = jax.random.split(rng, 2)
     p: Params = {"norm1": init_norm(cfg)}
-    if spec.mixer == ATTN:
+    if spec.mixer == ATTN and cfg.mla:
+        p["mixer"] = mla_lib.init_mla(ks[0], cfg)
+    elif spec.mixer == ATTN:
         p["mixer"] = init_attention(ks[0], cfg)
     elif spec.mixer == MAMBA:
         p["mixer"] = init_mamba(ks[0], cfg)
@@ -80,14 +83,23 @@ def init_params(rng, cfg: ModelConfig) -> Params:
 # ----------------------------------------------------------------------------
 def _apply_block(bp: Params, x: jnp.ndarray, cfg: ModelConfig,
                  spec: BlockSpec, mode: str, gst: Optional[Dict],
-                 ctx: Dict) -> Tuple[jnp.ndarray, Optional[Dict], jnp.ndarray]:
-    """Returns (x_out, new_group_state (or None), moe_aux)."""
+                 ctx: Dict, experts: Optional[Tuple[Dict, Any]] = None
+                 ) -> Tuple[jnp.ndarray, Optional[Dict], jnp.ndarray]:
+    """Returns (x_out, new_group_state (or None), moe_aux).  In verify mode
+    an expert block adds ``expert_rows`` (B, held experts) to its group's
+    entry: the rows of each slot's verify call routed to each held
+    expert.  ``experts``: (the group's expert weights stacked over its
+    layers, this layer's index), for a grouped-matmul expert block whose
+    ``bp`` leaves them out (``_whole_experts``)."""
     aux = jnp.zeros((), jnp.float32)
     h = apply_norm(bp["norm1"], x, cfg)
     new_gst: Optional[Dict] = None
     K = ctx.get("k_rows")  # static int for verify mode
 
-    if spec.mixer == ATTN:
+    if spec.mixer == ATTN and cfg.mla:
+        y, new_gst = mla_lib.attn_block(bp["mixer"], h, cfg, mode, gst, ctx)
+
+    elif spec.mixer == ATTN:
         if mode in ("full", "prefill"):
             y, (k_new, v_new) = attn_full(bp["mixer"], h, cfg,
                                           ctx["positions"])
@@ -99,8 +111,8 @@ def _apply_block(bp: Params, x: jnp.ndarray, cfg: ModelConfig,
                     kc, vc = paged_kv_write(gst["k"], gst["v"], k_new,
                                             v_new, ctx["slots"])
                 else:
-                    kc, vc = prefill_write(cfg, gst["k"], gst["v"], k_new,
-                                           v_new)
+                    kc, vc = prefill_write(cfg, (gst["k"], gst["v"]),
+                                           (k_new, v_new))
                 new_gst = {"k": kc, "v": vc}
         elif mode in ("decode", "replay"):
             # Bifurcated decode (= verify with k=1): the query block attends
@@ -121,7 +133,8 @@ def _apply_block(bp: Params, x: jnp.ndarray, cfg: ModelConfig,
                                         v_t[:, 0], ctx["slots"],
                                         gate=ctx.get("gate"))
             else:
-                kc, vc = kv_write(gst["k"], gst["v"], k_t[:, 0], v_t[:, 0],
+                kc, vc = kv_write((gst["k"], gst["v"]),
+                                  (k_t[:, 0], v_t[:, 0]),
                                   ctx["slots"], gate=ctx.get("gate"))
             new_gst = {"k": kc, "v": vc}
         elif mode == "verify":
@@ -238,7 +251,15 @@ def _apply_block(bp: Params, x: jnp.ndarray, cfg: ModelConfig,
     if spec.mlp != NO_MLP:
         h2 = apply_norm(bp["norm2"], x, cfg)
         if spec.mlp == MOE:
-            y2, aux = moe_lib.apply_moe(bp["mlp"], h2, cfg)
+            mlp, layer = bp["mlp"], None
+            if experts is not None:
+                mlp, layer = {**mlp, **experts[0]}, experts[1]
+            y2, aux, routed = moe_lib.apply_moe(mlp, h2, cfg, mode, layer)
+            if mode == "verify":
+                # (B*K*W1 tokens, held) -> per slot
+                rows = routed.reshape(x.shape[0] // K, -1, routed.shape[-1])
+                new_gst = {**(new_gst or {}),
+                           "expert_rows": rows.sum(1)}
         else:
             y2 = apply_mlp(bp["mlp"], h2, cfg, spec.mlp)
         x = x + y2.astype(x.dtype)
@@ -248,6 +269,20 @@ def _apply_block(bp: Params, x: jnp.ndarray, cfg: ModelConfig,
 # ----------------------------------------------------------------------------
 # full stack
 # ----------------------------------------------------------------------------
+def _whole_experts(cfg: ModelConfig, spec: BlockSpec, gp: Params):
+    """A grouped-matmul expert block's params without its expert weights,
+    and those weights whole, stacked over the group's layers (else None).
+    The grouped matmul reads a layer's experts in place: sliced per layer,
+    as the scan slices everything else, they would be copied before every
+    call (the whole layer's experts, since the kernel's operand must be an
+    array)."""
+    if spec.mlp != MOE or cfg.moe_impl != "gmm":
+        return gp, None
+    mlp = dict(gp["mlp"])
+    whole = {k: mlp.pop(k) for k in moe_lib.EXPERT_WEIGHTS}
+    return {**gp, "mlp": mlp}, whole
+
+
 def run_stack(params: Params, cfg: ModelConfig, x: jnp.ndarray, mode: str,
               state: Optional[Dict], ctx: Dict,
               remat: bool = False) -> Tuple[jnp.ndarray, Dict, jnp.ndarray]:
@@ -258,10 +293,12 @@ def run_stack(params: Params, cfg: ModelConfig, x: jnp.ndarray, mode: str,
     # prefix layers (unrolled)
     for i, spec in enumerate(cfg.prefix_blocks):
         gid = f"pre{i}"
-        bp = jax.tree_util.tree_map(lambda a: a[0], params[gid])
+        gp, whole = _whole_experts(cfg, spec, params[gid])
+        bp = jax.tree_util.tree_map(lambda a: a[0], gp)
         gst = (jax.tree_util.tree_map(lambda a: a[0], state["groups"][gid])
                if state is not None and gid in state["groups"] else None)
-        x, ngst, aux = _apply_block(bp, x, cfg, spec, mode, gst, ctx)
+        x, ngst, aux = _apply_block(bp, x, cfg, spec, mode, gst, ctx,
+                                    None if whole is None else (whole, 0))
         aux0 = aux0 + aux
         if ngst is not None:
             new_groups[gid] = jax.tree_util.tree_map(lambda a: a[None], ngst)
@@ -269,21 +306,30 @@ def run_stack(params: Params, cfg: ModelConfig, x: jnp.ndarray, mode: str,
     # periodic body: one scan over R periods
     P = cfg.pattern_period
     gids = [f"p{j}" for j in range(P)]
-    xs_params = tuple(params[g] for g in gids)
+    split = [_whole_experts(cfg, cfg.block_pattern[j], params[g])
+             for j, g in enumerate(gids)]
+    xs_params = tuple(gp for gp, _ in split)
+    wholes = tuple(w for _, w in split)
     xs_state = None
     if state is not None:
         xs_state = tuple(state["groups"].get(g) for g in gids)
+    # the layer index rides along only where a block needs it
+    xs = (xs_params, xs_state)
+    if any(w is not None for w in wholes):
+        xs += (jnp.arange(cfg.num_periods, dtype=jnp.int32),)
 
     from ..distributed import act_sharding
 
     def body(carry, xs):
         xc, aux = carry
-        ps, sts = xs
+        ps, sts = xs[:2]
+        r = xs[2] if len(xs) > 2 else None
         new_sts = []
         for j in range(P):
             gst = sts[j] if sts is not None else None
-            xc, ngst, a = _apply_block(ps[j], xc, cfg, cfg.block_pattern[j],
-                                       mode, gst, ctx)
+            xc, ngst, a = _apply_block(
+                ps[j], xc, cfg, cfg.block_pattern[j], mode, gst, ctx,
+                None if wholes[j] is None else (wholes[j], r))
             xc = act_sharding.constrain(xc, "residual")
             new_sts.append(ngst)
             aux = aux + a
@@ -299,8 +345,7 @@ def run_stack(params: Params, cfg: ModelConfig, x: jnp.ndarray, mode: str,
         carry = (x, aux0)
         ys_list = []
         for r in range(R):
-            xs_r = jax.tree_util.tree_map(lambda a: a[r],
-                                          (xs_params, xs_state))
+            xs_r = jax.tree_util.tree_map(lambda a: a[r], xs)
             carry, y_r = body(carry, xs_r)
             ys_list.append(y_r)
         x, aux_total = carry
@@ -308,8 +353,7 @@ def run_stack(params: Params, cfg: ModelConfig, x: jnp.ndarray, mode: str,
         ys = (jax.tree_util.tree_map(lambda *a: jnp.stack(a), *ys_list)
               if has_ys else ys_list[0])
     else:
-        (x, aux_total), ys = jax.lax.scan(body, (x, aux0),
-                                          (xs_params, xs_state))
+        (x, aux_total), ys = jax.lax.scan(body, (x, aux0), xs)
     for gid, ngst in zip(gids, ys):
         if ngst is not None:
             new_groups[gid] = ngst

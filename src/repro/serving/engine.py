@@ -540,6 +540,9 @@ class ServingEngine:
             accept_hist_np = np.asarray(state.stats["accept_hist"])  # repro-lint: allow(host-sync): batched retire-round readback
             arm_pulls_np = (np.asarray(state.stats["arm_pulls"])  # repro-lint: allow(host-sync): batched retire-round readback
                             if self._arms else None)
+            experts_np = ({k: np.asarray(state.stats[k])  # repro-lint: allow(host-sync): batched retire-round readback
+                           for k in ("expert_rows", "expert_rows_max")}
+                          if "expert_rows" in state.stats else None)
         retired: List[Request] = []
         for slot, req in self._slots.occupied():
             if not done[slot]:
@@ -562,6 +565,11 @@ class ServingEngine:
                     # per-request admit->retire latency
                     "latency_s": time.perf_counter() - req.stats["admit_t"],
                 }
+                if experts_np is not None:
+                    # rows its verify calls routed to the held experts:
+                    # in all, and the most one expert of one layer took
+                    req.stats.update({k: int(v[slot])
+                                      for k, v in experts_np.items()})
                 if arm_pulls_np is not None:
                     # the slot's bandit history, read BEFORE release
                     # zeroes it

@@ -25,11 +25,14 @@ def test_moe_scatter_matches_dense():
                       **F32).validate()
     p = moe_lib.init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 32))
-    yd, auxd = moe_lib.moe_dense(p, x, cfg)
-    ys, auxs = moe_lib.moe_scatter(p, x, cfg)
+    yd, auxd, rd = moe_lib.moe_dense(p, x, cfg)
+    ys, auxs, rs = moe_lib.moe_scatter(p, x, cfg)
     np.testing.assert_allclose(np.asarray(ys), np.asarray(yd),
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(float(auxs), float(auxd), rtol=1e-5)
+    # both count each row's top-k once per expert
+    np.testing.assert_array_equal(np.asarray(rs), np.asarray(rd))
+    assert np.asarray(rd).sum(1).tolist() == [2] * 16
 
 
 def test_moe_shared_experts_added():
@@ -40,7 +43,7 @@ def test_moe_shared_experts_added():
     p = moe_lib.init_moe(jax.random.PRNGKey(0), cfg)
     assert p["shared_gate"].shape == (32, 32)  # 2 shared * e_ff 16
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 4, 32))
-    y, _ = moe_lib.apply_moe(p, x, cfg)
+    y, _, _ = moe_lib.apply_moe(p, x, cfg)
     assert y.shape == x.shape and bool(jnp.isfinite(y).all())
 
 
@@ -52,7 +55,7 @@ def test_moe_capacity_drops_are_bounded():
                       **F32).validate()
     p = moe_lib.init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 16, 16))
-    y, _ = moe_lib.moe_scatter(p, x, cfg)
+    y, _, _ = moe_lib.moe_scatter(p, x, cfg)
     assert bool(jnp.isfinite(y).all())
 
 
@@ -146,6 +149,6 @@ def test_prefill_write_longer_than_ring():
     vc = jnp.zeros((B, S, KV, hd))
     k_new = jnp.arange(T, dtype=jnp.float32)[None, :, None, None] * jnp.ones(
         (B, T, KV, hd))
-    kc2, _ = prefill_write(cfg, kc, vc, k_new, k_new)
+    kc2, _ = prefill_write(cfg, (kc, vc), (k_new, k_new))
     # slot s holds the largest pos < 7 with pos % 4 == s -> [4, 5, 6, 3]
     np.testing.assert_array_equal(np.asarray(kc2[0, :, 0, 0]), [4, 5, 6, 3])

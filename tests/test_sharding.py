@@ -324,3 +324,49 @@ def test_every_assigned_arch_has_full_param_coverage():
                     for a in axes:
                         size *= mesh.shape[a]
                     assert d % size == 0, (arch, path, spec, leaf.shape)
+
+
+def test_mla_and_held_expert_leaves_get_a_placement():
+    """DeepSeek-V2-Lite on a described v5e-4 host (data 1, model 4): the
+    per-head MLA projections split heads, the down-projection to the
+    shared latent and its norm replicate over "model", the 16 held
+    experts split 4 to a chip, and the latent cache splits its sequence
+    (it has one shared head, so no head axis to split)."""
+    import jax
+
+    from repro.configs import get_config
+    from repro.core.spec_engine import SpecConfig, empty_decode_state
+    from repro.models import model as M
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"),
+                              experts_held=16)
+    mesh = FakeMesh({"data": 1, "model": 4})
+    shapes = jax.eval_shape(lambda r: M.init_params(r, cfg),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    specs = {"/".join(shd._path_names(p)): tuple(shd.param_pspec(mesh, p, x))
+             for p, x in jax.tree_util.tree_flatten_with_path(shapes)[0]}
+    assert specs["p0/mixer/wq"] == (None, "data", "model")
+    assert specs["p0/mixer/wkv_a"] == (None, "data", None)
+    assert specs["p0/mixer/kv_norm"] == (None, None)
+    assert specs["p0/mixer/wkv_b"] == (None, None, "model")
+    assert specs["p0/mixer/wo"] == (None, "model", "data")
+    assert shapes["p0"]["mlp"]["w_gate"].shape == (26, 16, 2048, 1408)
+    assert specs["p0/mlp/w_gate"] == (None, "model", "data", None)
+    assert specs["p0/mlp/w_down"] == (None, "model", None, "data")
+    assert specs["p0/mlp/router"] == (None, "data", None)
+    state = jax.eval_shape(lambda: empty_decode_state(cfg, SpecConfig(), 8,
+                                                      524))
+    lat = state.model["groups"]["p0"]["latent"]
+    assert lat.shape == (26, 8, 524, 576)
+    path = _dpath("model", "groups", "p0", "latent")
+    assert tuple(shd.decode_state_pspec(mesh, path, lat, strict=True)) == \
+        (None, "data", "model", None)
+    assert tuple(shd.decode_state_pspec(
+        mesh, _dpath("stats", "expert_rows"), state.stats["expert_rows"],
+        strict=True)) == ("data",)
+    # a cache split over its sequence keeps the commit's scatter
+    from repro.distributed import act_sharding as act
+    act.install(mesh)
+    try:
+        assert act.splits_cache_rows(lat.shape)
+    finally:
+        act.uninstall()

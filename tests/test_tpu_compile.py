@@ -10,6 +10,8 @@ Shapes are StableLM-2-1.6B's at serving widths (bf16, 32 heads, 32 KV
 heads, head_dim 64; 8 slots of 2048 positions; cache block 512).  The
 speculative commit is compiled at the served shapes of the benchmark's
 decode-b4 cell (4 slots of 1024 positions), alone and inside the step.
+The held experts' grouped matmul is compiled at DeepSeek-V2-Lite's widths
+(16 experts of 2048 x 1408), alone and inside that model's served step.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -28,6 +30,7 @@ from repro.core import spec_engine as E
 from repro.core import tree as T
 from repro.core.ngram_tables import abstract_tables
 from repro.kernels import dispatch, ops
+from repro.kernels.expert_gmm import expert_gmm_call
 from repro.kernels.ngram_match import LANE, TILE, ngram_match_call
 from repro.kernels.spec_attention import (paged_spec_attention_call,
                                           spec_attention_call)
@@ -215,3 +218,57 @@ def test_spec_step_updates_the_cache_in_place(one_chip, monkeypatch):
     assert dispatch.kernels_in_hlo(hlo) == ("ngram_match", "spec_attention")
     assert _cache_copies(hlo, f"bf16[{R},{B},{S},32,64]") == []
     _assert_cache_aliased(hlo, state, "state")
+
+
+# ---------------------------------------------------------------------------
+# the held experts' grouped matmul, and the MLA + expert step around it
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
+def test_expert_gmm_compiles(one_chip, k, n):
+    """Gate/up (2048 -> 1408) and down (1408 -> 2048) over the rows of a
+    verify call of 8 slots x 110 positions, top-6 of 64, 16 experts held:
+    the dropless bound of 57 tiles of 128 rows; the weights of all 26
+    expert layers, read by layer index."""
+    s = _sds(one_chip)
+    tiles = (880 * 6 + 16 * 127) // 128
+    fn = lambda x, w, r, g, live: expert_gmm_call(x, w, r, g, live, tm=128)
+    hlo = _compile(fn, s((tiles * 128, k), DT), s((26, 16, k, n), DT),
+                   s((), jnp.int32), s((tiles,), jnp.int32),
+                   s((), jnp.int32))
+    assert dispatch.kernels_in_hlo(hlo) == ("expert_gmm",)
+
+
+def test_mla_expert_step_updates_the_latent_cache_in_place(one_chip,
+                                                           monkeypatch):
+    """DeepSeek-V2-Lite's served step at published widths, cut to its
+    dense layer and two expert layers (one layer alone fits the chip's
+    fast memory, and XLA prefetches it there whole), 8 slots of 524
+    positions: the grouped matmul and the drafter kernel compiled for the
+    chip, no copy of the latent cache, and the cache aliased to the
+    donated state."""
+    import dataclasses
+    from repro.configs import get_config
+    monkeypatch.setattr(dispatch, "default_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"), num_layers=3,
+                              experts_held=16, backend="pallas",
+                              param_dtype=DT, compute_dtype=DT).validate()
+    spec = E.SpecConfig(backend="pallas")
+    params = _on_chip(jax.eval_shape(
+        lambda: M.init_params(jax.random.PRNGKey(0), cfg)), one_chip)
+    state = _on_chip(jax.eval_shape(
+        lambda: E.empty_decode_state(cfg, spec, 8, 524)), one_chip)
+    tables = _on_chip(abstract_tables(cfg.vocab_size, spec.k, spec.w),
+                      one_chip)
+    hlo = E.spec_step.lower(params, cfg, spec, state,
+                            tables).compile().as_text()
+    assert dispatch.kernels_in_hlo(hlo) == ("expert_gmm", "ngram_match")
+    assert _cache_copies(hlo, "bf16[2,8,524,576]") == []
+    # the kernel reads the stacked experts in place: no layer of them is
+    # sliced out (copied) before a call
+    assert not re.search(r"= bf16\[16,(2048,1408|1408,2048)\]", hlo)
+    alias = {int(o): int(p) for o, p in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}, may-alias\)", hlo.splitlines()[0])}
+    flat = jax.tree_util.tree_flatten_with_path(state)[0]
+    latent = [i for i, (path, _) in enumerate(flat)
+              if jax.tree_util.keystr(path).endswith("['latent']")]
+    assert len(latent) == 2 and all(i in alias for i in latent)
